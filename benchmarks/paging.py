@@ -78,15 +78,15 @@ def kernel_rows() -> None:
     ps = 64
     npp = s // ps
     n_pages = b * npp + 1
-    kp = jnp.zeros((n_pages, ps, hkv, d), kc.dtype)
-    vp = jnp.zeros((n_pages, ps, hkv, d), vc.dtype)
+    kp = jnp.zeros((n_pages, hkv, ps, d), kc.dtype)
+    vp = jnp.zeros((n_pages, hkv, ps, d), vc.dtype)
     perm = np.random.default_rng(0).permutation(np.arange(1, n_pages))
     bt = jnp.asarray(perm.reshape(b, npp), jnp.int32)
     for bb in range(b):
         for lp in range(npp):
             pg = int(bt[bb, lp])
-            kp = kp.at[pg].set(kc[bb, lp * ps:(lp + 1) * ps])
-            vp = vp.at[pg].set(vc[bb, lp * ps:(lp + 1) * ps])
+            kp = kp.at[pg].set(kc[bb, lp * ps:(lp + 1) * ps].swapaxes(0, 1))
+            vp = vp.at[pg].set(vc[bb, lp * ps:(lp + 1) * ps].swapaxes(0, 1))
     for label in ("none", "con2", "gap2"):
         cfg = CoarseningConfig.parse(label) if label != "none" \
             else CoarseningConfig()

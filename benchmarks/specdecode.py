@@ -67,9 +67,9 @@ def kernel_rows() -> None:
     key = jax.random.PRNGKey(0)
     n_pages = MB * (MS // MPS) + 1
     kp = jax.random.normal(jax.random.fold_in(key, 1),
-                           (n_pages, MPS, MHKV, MD), jnp.float32)
+                           (n_pages, MHKV, MPS, MD), jnp.float32)
     vp = jax.random.normal(jax.random.fold_in(key, 2),
-                           (n_pages, MPS, MHKV, MD), jnp.float32)
+                           (n_pages, MHKV, MPS, MD), jnp.float32)
     perm = np.random.default_rng(0).permutation(np.arange(1, n_pages))
     bt = jnp.asarray(perm.reshape(MB, MS // MPS), jnp.int32)
     for t in (3, 5, 9):                      # K in {2, 4, 8}

@@ -12,13 +12,14 @@ BODY = r"""
 import os, signal, tempfile, threading
 import jax
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.launch.train import train
 
 cfg = get_config("qwen3-0.6b").reduced()
 ckpt = tempfile.mkdtemp(prefix="elastic_")
 
 print("phase 1: mesh (4,2), SIGTERM arrives mid-run")
-m1 = jax.make_mesh((4, 2), ("data", "model"))
+m1 = make_mesh((4, 2), ("data", "model"))
 timer = threading.Timer(10.0, lambda: signal.raise_signal(signal.SIGTERM))
 timer.start()
 l1, _ = train(cfg, steps=400, batch=8, seq=64, ckpt_dir=ckpt,
@@ -27,7 +28,7 @@ timer.cancel()
 print(f"  preempted after {len(l1)} steps; checkpointed")
 
 print("phase 2: node lost -> restart on mesh (8,1) from the checkpoint")
-m2 = jax.make_mesh((8, 1), ("data", "model"))
+m2 = make_mesh((8, 1), ("data", "model"))
 l2, _ = train(cfg, steps=len(l1) + 10, batch=8, seq=64, ckpt_dir=ckpt,
               save_every=100, mesh=m2, log_every=5)
 assert l2[0] < l1[0] + 0.5, "must continue, not restart"

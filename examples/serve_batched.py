@@ -22,7 +22,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_config
-from repro.launch.serve import BatchedServer
+from repro.launch.serve import BatchedServer, device_name
 from repro.models import model as M
 from repro.serve import PagedEngine, Scheduler
 
@@ -81,7 +81,7 @@ def main():
         rate = engine.decoded_tokens / max(engine.decode_s, 1e-9)
         print(f"{len(done)} requests in {dt:.2f}s | pool "
               f"{engine.pool.capacity} pages x {engine.page_size} tok | "
-              f"decode {rate:.1f} tok/s (CPU interpret-scale)")
+              f"decode {rate:.1f} tok/s ({device_name()})")
     else:
         server = BatchedServer(cfg, params, slots=args.slots,
                                max_len=args.max_len, chunk=args.chunk,
@@ -100,7 +100,7 @@ def main():
             print(f"req {i}: -> {out[:8]}...")
         print(f"{len(server.completed)} requests in {dt:.2f}s | decode "
               f"{server.decoded_tokens / max(server.decode_s, 1e-9):.1f} "
-              f"tok/s (CPU interpret-scale)")
+              f"tok/s ({device_name()})")
 
 
 if __name__ == "__main__":

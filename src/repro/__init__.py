@@ -5,12 +5,3 @@ vectorization) lives in `repro.core` + `repro.kernels`; the production
 substrate (models, data, optim, checkpoint, runtime, distributed, launch)
 makes it deployable at multi-pod scale.  See DESIGN.md.
 """
-import jax as _jax
-
-# The legacy (non-partitionable) threefry lowering is not sharding-stable:
-# the same lm_init under jit with sharded out_shardings yields DIFFERENT
-# weights per mesh shape (GSPMD partitions the key-expansion differently),
-# which breaks every cross-mesh equivalence (elastic restart, sharded-vs-
-# single-device train step).  Partitionable threefry is sharding-invariant
-# by construction, so init/dropout match bit-for-bit across mesh shapes.
-_jax.config.update("jax_threefry_partitionable", True)

@@ -221,12 +221,9 @@ def pallas_stream_call(body: Callable, plan: StreamPlan, n_in: int,
 
         in_specs = [remap(s) for s in in_specs]
         out_spec = remap(out_spec)
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"))
-        except Exception:            # interpret-only environments
-            pass
+        from jax.experimental.pallas import tpu as pltpu
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"))
     else:
         grid = (plan.grid,)
 

@@ -109,18 +109,12 @@ class Roofline:
 def analyze(compiled, hlo_text: str, chips: int,
             model_flops: float = 0.0) -> Roofline:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns [dict]
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     bytes_accessed = float(ca.get("bytes accessed", 0.0))
     coll = collective_bytes(hlo_text)["total"]
-    mem = getattr(compiled, "memory_analysis", lambda: None)()
-    bpd = 0.0
-    if mem is not None:
-        bpd = float(getattr(mem, "temp_size_in_bytes", 0) +
-                    getattr(mem, "argument_size_in_bytes", 0) +
-                    getattr(mem, "output_size_in_bytes", 0) -
-                    getattr(mem, "alias_size_in_bytes", 0))
+    mem = compiled.memory_analysis()
+    bpd = float(mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     # cost_analysis flops/bytes are program-wide per device under SPMD
     compute_s = flops / PEAK_FLOPS_BF16
     memory_s = bytes_accessed / HBM_BW

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.optim.compression import plan_buckets, bucket_coarsen, bucket_restore
 
@@ -29,8 +28,8 @@ def bucketed_psum(grads: Any, *, mesh: Mesh, axis: str = "data",
 
     buckets = bucket_coarsen(grads, plan)
     specs = tuple(P() for _ in buckets)
-    reduced = shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
-                        check_rep=False)(*buckets)
+    reduced = jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
+                        check_vma=False)(*buckets)
     return bucket_restore(list(reduced), plan)
 
 
@@ -62,8 +61,8 @@ def int8_ef_psum(grads: Any, residual: Any, *, mesh: Mesh,
 
     specs = tuple(P() for _ in range(2 * len(leaves_q)))
     out_specs = tuple(P() for _ in leaves_q)
-    reduced = shard_map(body, mesh=mesh, in_specs=specs, out_specs=out_specs,
-                        check_rep=False)(*leaves_q, *leaves_s)
+    reduced = jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=out_specs,
+                        check_vma=False)(*leaves_q, *leaves_s)
     return jax.tree.unflatten(treedef, reduced), new_resid
 
 
@@ -75,6 +74,6 @@ def pertensor_psum(grads: Any, *, mesh: Mesh, axis: str = "data"):
         return tuple(lax.psum(l, axis) for l in ls)
 
     specs = tuple(P() for _ in leaves)
-    reduced = shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
-                        check_rep=False)(*leaves)
+    reduced = jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
+                        check_vma=False)(*leaves)
     return jax.tree.unflatten(treedef, reduced)

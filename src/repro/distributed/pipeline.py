@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
@@ -68,10 +67,10 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
                         axis)
         return outs
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),      # params sharded by stage; data replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, micros)
     return out.reshape(b, *x.shape[1:])

@@ -28,8 +28,10 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.coarsening import CoarseningConfig, KIND_GAPPED
+from repro.kernels.flash_attention import _dot_nt
 
 NEG = -1e30
 
@@ -205,38 +207,37 @@ def make_kernel(b: int, h: int, hkv: int, s: int, d: int,
     return run
 
 
-def make_paged_kernel(b: int, h: int, hkv: int, n_pages: int, npp: int,
-                      d: int, cfg: CoarseningConfig, *, page_size: int = 64,
-                      window: int | None = None, scale: float | None = None,
-                      kv_bits: int | None = None,
-                      interpret: bool = True) -> Callable:
-    """Split-KV decode attention through a per-slot BLOCK TABLE.
+def _combine_splits(m, l, acc):
+    """`_combine` for the paged kernels' split-major layout: m, l
+    (B, Hkv, n_splits, R, 1), acc (B, Hkv, n_splits, R, D) -> (B, Hkv, R, D)."""
+    m_max = m.max(axis=2, keepdims=True)
+    w = jnp.exp(m - m_max)
+    w = jnp.where(m <= NEG * 0.5, 0.0, w)           # dead splits contribute 0
+    l_tot = (l * w).sum(axis=2)
+    out = (acc * w).sum(axis=2)
+    l_tot = jnp.where(l_tot == 0.0, 1.0, l_tot)
+    return out / l_tot
 
-    The caches arrive as a global page pool shared by every slot —
-    k/v: (P, page_size, Hkv, D) — and each slot's logical cache row ``r``
-    lives at pool row ``(block_table[slot, r // page_size], r % page_size)``.
-    The kv block IS the page (bkv == page_size), so the coarsening axis is
-    the LOGICAL-PAGE axis of the slot: each program owns C logical pages,
 
-      consecutive : C adjacent logical pages
-      gapped      : C logical pages strided npp/C apart
+def _paged_call(b: int, h: int, hkv: int, t: int, n_pages: int, npp: int,
+                d: int, cfg: CoarseningConfig, *, page_size: int,
+                window: int | None, scale: float | None,
+                kv_bits: int | None, interpret: bool) -> Callable:
+    """The block-table split-KV kernel shared by paged decode (t=1) and
+    batched verify (t drafted rows per slot).
 
-    and in BOTH cases the physical fetch is C table-resolved page loads —
-    paging is the paper's *gapped* access pattern with the fixed stride
-    replaced by the block-table indirection (C narrow cached LSUs,
-    Fig. 4 bottom); coarsening amortizes the per-page issue + table-lookup
-    overhead exactly as it amortizes the strided DMA issue overhead.
+    Pools arrive as (P, Hkv, page_size, D) and stay in HBM
+    (``memory_space=ANY``): each program resolves its C logical pages
+    through the block table (scalar-prefetched into SMEM together with the
+    slot positions) and DMAs exactly those C per-head page tiles into a
+    VMEM scratch — the paper's coarsened LSU, C table-resolved page
+    fetches per program.  VMEM use is therefore C pages per operand,
+    independent of the pool size.
 
-    Logical pages past a slot's allocation sit at NULL_PAGE in the table;
-    their rows are beyond ``pos`` and the causal mask (which also covers
-    partially-filled tail pages) zeroes them out of the softmax.
-
-    Returned callable:
-      run(q (B,1,H,D), k_pool, v_pool (P,ps,Hkv,D), block_table (B,npp)
-          int32, pos (B,) int32) -> (B,1,H,D)
-    ``kv_bits=8``: pools are int8 with (P,ps,Hkv) f32 scale pools and the
-    callable takes (q, k_pool, v_pool, k_scale, v_scale, block_table, pos);
-    dequant is fused into the same VMEM pass as the contiguous kernel.
+    call(pos0 (B,), table (B*npp,), q (B,Hkv,T*G,D), k_pool, v_pool
+    [, k_scale, v_scale (P,Hkv,ps)]) -> split partials (m, l, acc) in the
+    layout `_combine_splits` reads; q row ``ti*G + gi`` sits at cache
+    position ``pos0 + ti``.
     """
     c = cfg.degree
     ps = page_size
@@ -246,72 +247,86 @@ def make_paged_kernel(b: int, h: int, hkv: int, n_pages: int, npp: int,
     g = h // hkv
     if g * hkv != h:
         raise ValueError(f"n_heads {h} not divisible by n_kv_heads {hkv}")
-    n_splits = npp // c
-    seg = npp // c                       # gapped logical-page stride
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
     if kv_bits not in (None, 8):
         raise ValueError(f"kv_bits must be None or 8, got {kv_bits}")
     quant = kv_bits == 8
+    n_splits = npp // c
+    seg = npp // c                       # gapped logical-page stride
+    rows = t * g                         # fused q rows per program
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
     def logical_page(si, j):
         return (j * seg + si) if gapped else (si * c + j)
 
-    def body(pos_ref, bt_ref, q_ref, k_ref, v_ref, *refs):
+    def body(pos_ref, bt_ref, q_ref, k_hbm, v_hbm, *refs):
         if quant:
-            ks_ref, vs_ref, m_ref, l_ref, acc_ref = refs
+            ks_hbm, vs_hbm, m_ref, l_ref, acc_ref, k_buf, v_buf, ks_buf, \
+                vs_buf, sem = refs
         else:
-            m_ref, l_ref, acc_ref = refs
-        si = pl.program_id(2)
-        pos = pos_ref[0, 0]
+            m_ref, l_ref, acc_ref, k_buf, v_buf, sem = refs
+        bb, hh, si = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        pos0 = pos_ref[bb]
 
         # fused logical-row extent for the length-aware skip (page indices
-        # are logical, so the extent math matches the contiguous kernel's)
+        # are logical, so the extent math matches the contiguous kernel's);
+        # the deepest row (pos0 + t - 1) reaches furthest right, the
+        # shallowest (pos0) bounds the window skip on the left
         if gapped:
             first_row = si * ps
             last_row = ((c - 1) * seg + si) * ps + ps - 1
         else:
             first_row = si * c * ps
             last_row = (si * c + c - 1) * ps + ps - 1
-        live = first_row <= pos
+        live = first_row <= pos0 + (t - 1)
         if window is not None:
-            live &= last_row > pos - window
+            live &= last_row > pos0 - window
 
         @pl.when(live)
         def _compute():
-            q = q_ref[...].reshape(g, d).astype(jnp.float32)
-            m = jnp.full((g,), NEG, jnp.float32)
-            l = jnp.zeros((g,), jnp.float32)
-            acc = jnp.zeros((g, d), jnp.float32)
-            cols0 = jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-            for j in range(c):       # unrolled: C table-resolved page loads
-                lp = logical_page(si, j)
-                pp = bt_ref[0, lp]   # physical page (the table gather)
-                kj = pl.load(k_ref, (pl.dslice(pp, 1), slice(None),
-                                     slice(None), slice(None))
-                             ).reshape(ps, d).astype(jnp.float32)
-                vj = pl.load(v_ref, (pl.dslice(pp, 1), slice(None),
-                                     slice(None), slice(None))
-                             ).reshape(ps, d).astype(jnp.float32)
-                if quant:
-                    kj = kj * pl.load(
-                        ks_ref, (pl.dslice(pp, 1), slice(None), slice(None))
-                    ).reshape(ps, 1)
-                    vj = vj * pl.load(
-                        vs_ref, (pl.dslice(pp, 1), slice(None), slice(None))
-                    ).reshape(ps, 1)
-                cols = cols0 + lp * ps
-                mask = cols <= pos
+            # C table-resolved page fetches per operand, all in flight at once
+            pairs = [(k_hbm, k_buf), (v_hbm, v_buf)]
+            if quant:
+                pairs += [(ks_hbm, ks_buf), (vs_hbm, vs_buf)]
+            copies = []
+            for j in range(c):
+                pp = bt_ref[bb * npp + logical_page(si, j)]
+                for n, (src, dst) in enumerate(pairs):
+                    # a K/V page tile is (ps, D); a scale page is (1, ps)
+                    at = (pp, hh) if n < 2 else (pp, pl.ds(hh, 1))
+                    cp = pltpu.make_async_copy(src.at[at], dst.at[j],
+                                               sem.at[n, j])
+                    cp.start()
+                    copies.append(cp)
+            for cp in copies:
+                cp.wait()
+
+            q = q_ref[...].reshape(rows, d).astype(jnp.float32)
+            m = jnp.full((rows, 1), NEG, jnp.float32)
+            l = jnp.zeros((rows, 1), jnp.float32)
+            acc = jnp.zeros((rows, d), jnp.float32)
+            ri = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0)
+            tpos = pos0 + (jax.lax.div(ri, g) if t > 1 else 0)
+            cols0 = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
+            for j in range(c):       # unrolled: the C fetched pages
+                kj = k_buf[j].astype(jnp.float32)
+                vj = v_buf[j].astype(jnp.float32)
+                cols = cols0 + logical_page(si, j) * ps
+                mask = cols <= tpos
                 if window is not None:
-                    mask &= cols > pos - window
-                sij = jnp.dot(q, kj.T,
-                              preferred_element_type=jnp.float32) * scale
+                    mask &= cols > tpos - window
+                sij = _dot_nt(q, kj) * scale
+                if quant:
+                    # fused dequant: per-(row, kv-head) scales ride the lane
+                    # axis of the scores (K) and of the probabilities (V)
+                    sij = sij * ks_buf[j]
                 sij = jnp.where(mask, sij, NEG)
-                m_new = jnp.maximum(m, sij.max(axis=1))
-                p = jnp.exp(sij - m_new[:, None]) * mask
+                m_new = jnp.maximum(m, sij.max(axis=1, keepdims=True))
+                p = jnp.where(mask, jnp.exp(sij - m_new), 0.0)
                 alpha = jnp.exp(m - m_new)
-                l = l * alpha + p.sum(axis=1)
-                acc = acc * alpha[:, None] + jnp.dot(
-                    p, vj, preferred_element_type=jnp.float32)
+                l = l * alpha + p.sum(axis=1, keepdims=True)
+                pv = p * vs_buf[j] if quant else p
+                acc = acc * alpha + jnp.dot(
+                    pv, vj, preferred_element_type=jnp.float32)
                 m = m_new
             m_ref[...] = m.reshape(m_ref.shape)
             l_ref[...] = l.reshape(l_ref.shape)
@@ -323,56 +338,82 @@ def make_paged_kernel(b: int, h: int, hkv: int, n_pages: int, npp: int,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # the pool rides in whole (its page axis is gathered in-body, so no
-    # BlockSpec offset can window it); the head axis is still windowed
-    pool_spec = pl.BlockSpec((n_pages, ps, 1, d),
-                             lambda bb, hh, si: (0, 0, hh, 0))
-    sc_pool_spec = pl.BlockSpec((n_pages, ps, 1),
-                                lambda bb, hh, si: (0, 0, hh))
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda bb, hh, si: (bb, 0)),          # pos
-        pl.BlockSpec((1, npp), lambda bb, hh, si: (bb, 0)),        # table
-        pl.BlockSpec((1, 1, g, d), lambda bb, hh, si: (bb, hh, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    if quant:
-        in_specs += [sc_pool_spec, sc_pool_spec]
-
-    call = pl.pallas_call(
-        body,
-        grid=(b, hkv, n_splits),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, 1, g, 1), lambda bb, hh, si: (bb, hh, 0, si)),
-            pl.BlockSpec((1, 1, g, 1), lambda bb, hh, si: (bb, hh, 0, si)),
-            pl.BlockSpec((1, 1, g, 1, d),
-                         lambda bb, hh, si: (bb, hh, 0, si, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, hkv, g, n_splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, n_splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, n_splits, d), jnp.float32),
-        ),
-        interpret=interpret,
+    n_ops = 4 if quant else 2
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    split = lambda bb, hh, si, *_: (bb, hh, si, 0, 0)
+    out_shape = (
+        jax.ShapeDtypeStruct((b, hkv, n_splits, rows, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, hkv, n_splits, rows, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, hkv, n_splits, rows, d), jnp.float32),
     )
 
-    if quant:
-        def run(q, k_pool, v_pool, k_scale, v_scale, block_table, pos):
-            qv = q.reshape(b, hkv, g, d)
-            pos2 = pos.reshape(b, 1).astype(jnp.int32)
-            bt = block_table.astype(jnp.int32)
-            m, l, acc = call(pos2, bt, qv, k_pool, v_pool, k_scale, v_scale)
-            out = _combine(m, l, acc)                 # (B, Hkv, G, D)
-            return out.reshape(b, 1, h, d).astype(q.dtype)
-    else:
-        def run(q, k_pool, v_pool, block_table, pos):
-            qv = q.reshape(b, hkv, g, d)
-            pos2 = pos.reshape(b, 1).astype(jnp.int32)
-            bt = block_table.astype(jnp.int32)
-            m, l, acc = call(pos2, bt, qv, k_pool, v_pool)
-            out = _combine(m, l, acc)                 # (B, Hkv, G, D)
-            return out.reshape(b, 1, h, d).astype(q.dtype)
+    def call(pos0, block_table, q, *pools):
+        scratch = [pltpu.VMEM((c, ps, d), pools[0].dtype)] * 2
+        if quant:
+            scratch += [pltpu.VMEM((c, 1, ps), jnp.float32)] * 2
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                   # pos0, block table
+            grid=(b, hkv, n_splits),
+            in_specs=[pl.BlockSpec((1, 1, rows, d),
+                                   lambda bb, hh, si, *_: (bb, hh, 0, 0))]
+                     + [hbm] * n_ops,
+            out_specs=(pl.BlockSpec((1, 1, 1, rows, 1), split),
+                       pl.BlockSpec((1, 1, 1, rows, 1), split),
+                       pl.BlockSpec((1, 1, 1, rows, d), split)),
+            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((n_ops, c))],
+        )
+        return pl.pallas_call(body, grid_spec=grid_spec, out_shape=out_shape,
+                              interpret=interpret)(
+            pos0.astype(jnp.int32), block_table.astype(jnp.int32).reshape(-1),
+            q, *pools)
+
+    return call
+
+
+def make_paged_kernel(b: int, h: int, hkv: int, n_pages: int, npp: int,
+                      d: int, cfg: CoarseningConfig, *, page_size: int = 64,
+                      window: int | None = None, scale: float | None = None,
+                      kv_bits: int | None = None,
+                      interpret: bool = True) -> Callable:
+    """Split-KV decode attention through a per-slot BLOCK TABLE.
+
+    The caches arrive as a global page pool shared by every slot —
+    k/v: (P, Hkv, page_size, D) — and each slot's logical cache row ``r``
+    of kv head ``hh`` lives at ``pool[block_table[slot, r // page_size],
+    hh, r % page_size]``.  The kv block IS the page (bkv == page_size), so
+    the coarsening axis is the LOGICAL-PAGE axis of the slot: each program
+    owns C logical pages,
+
+      consecutive : C adjacent logical pages
+      gapped      : C logical pages strided npp/C apart
+
+    and in BOTH cases the physical fetch is C table-resolved page DMAs —
+    paging is the paper's *gapped* access pattern with the fixed stride
+    replaced by the block-table indirection (C narrow cached LSUs,
+    Fig. 4 bottom); coarsening amortizes the per-page issue + table-lookup
+    overhead exactly as it amortizes the strided DMA issue overhead.
+
+    Logical pages past a slot's allocation sit at NULL_PAGE in the table;
+    their rows are beyond ``pos`` and the causal mask (which also covers
+    partially-filled tail pages) zeroes them out of the softmax.
+
+    Returned callable:
+      run(q (B,1,H,D), k_pool, v_pool (P,Hkv,ps,D), block_table (B,npp)
+          int32, pos (B,) int32) -> (B,1,H,D)
+    ``kv_bits=8``: pools are int8 with (P,Hkv,ps) f32 scale pools and the
+    callable takes (q, k_pool, v_pool, k_scale, v_scale, block_table, pos);
+    the dequant is fused into the same VMEM pass.
+    """
+    call = _paged_call(b, h, hkv, 1, n_pages, npp, d, cfg,
+                       page_size=page_size, window=window, scale=scale,
+                       kv_bits=kv_bits, interpret=interpret)
+    g = h // hkv
+
+    def run(q, *args):
+        *pools, block_table, pos = args
+        out = _combine_splits(*call(pos, block_table,
+                                    q.reshape(b, hkv, g, d), *pools))
+        return out.reshape(b, 1, h, d).astype(q.dtype)
 
     return run
 
@@ -386,11 +427,11 @@ def make_verify_kernel(b: int, h: int, hkv: int, t: int, n_pages: int,
     """Batched-verify attention through a per-slot block table (short-q
     flash: the speculative-decode geometry).
 
-    Structurally this is `make_paged_kernel` generalized from one query row
-    to T drafted rows per slot: the coarsening axis is still the slot's
-    LOGICAL-PAGE axis (each program owns C table-resolved page loads), but
-    every fused page is now scored against a (T*G, D) q pane — row t of
-    slot b sits at cache position ``pos0[b] + t`` and carries its own
+    This is `make_paged_kernel` generalized from one query row to T drafted
+    rows per slot: the coarsening axis is still the slot's LOGICAL-PAGE
+    axis (each program owns C table-resolved page fetches), but every
+    fused page is now scored against a (T*G, D) q pane — row t of slot b
+    sits at cache position ``pos0[b] + t`` and carries its own
     causal/window mask.  That changes the economics the tuner sees: decode
     (t=1) amortizes the per-page issue + table-lookup latency over G query
     rows, verify amortizes it over T*G rows, so the memory/compute
@@ -398,156 +439,23 @@ def make_verify_kernel(b: int, h: int, hkv: int, t: int, n_pages: int,
     ``flash_attention_verify`` tuner family).
 
     Returned callable:
-      run(q (B,T,H,D), k_pool, v_pool (P,ps,Hkv,D), block_table (B,npp)
+      run(q (B,T,H,D), k_pool, v_pool (P,Hkv,ps,D), block_table (B,npp)
           int32, pos0 (B,) int32) -> (B,T,H,D)
-    ``kv_bits=8``: int8 pools + (P,ps,Hkv) f32 scale pools, callable takes
+    ``kv_bits=8``: int8 pools + (P,Hkv,ps) f32 scale pools, callable takes
     (q, k_pool, v_pool, k_scale, v_scale, block_table, pos0).
     """
-    c = cfg.degree
-    ps = page_size
-    if npp % c:
-        raise ValueError(f"slot pages {npp} not tileable by degree {c}")
-    gapped = cfg.kind == KIND_GAPPED
+    call = _paged_call(b, h, hkv, t, n_pages, npp, d, cfg,
+                       page_size=page_size, window=window, scale=scale,
+                       kv_bits=kv_bits, interpret=interpret)
     g = h // hkv
-    if g * hkv != h:
-        raise ValueError(f"n_heads {h} not divisible by n_kv_heads {hkv}")
-    n_splits = npp // c
-    seg = npp // c                       # gapped logical-page stride
-    rows = t * g                         # fused q rows per program
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    if kv_bits not in (None, 8):
-        raise ValueError(f"kv_bits must be None or 8, got {kv_bits}")
-    quant = kv_bits == 8
 
-    def logical_page(si, j):
-        return (j * seg + si) if gapped else (si * c + j)
-
-    def body(pos_ref, bt_ref, q_ref, k_ref, v_ref, *refs):
-        if quant:
-            ks_ref, vs_ref, m_ref, l_ref, acc_ref = refs
-        else:
-            m_ref, l_ref, acc_ref = refs
-        si = pl.program_id(2)
-        pos0 = pos_ref[0, 0]
-
-        if gapped:
-            first_row = si * ps
-            last_row = ((c - 1) * seg + si) * ps + ps - 1
-        else:
-            first_row = si * c * ps
-            last_row = (si * c + c - 1) * ps + ps - 1
-        # the deepest drafted row (pos0 + t - 1) reaches furthest right; the
-        # shallowest (pos0) bounds the window skip on the left
-        live = first_row <= pos0 + (t - 1)
-        if window is not None:
-            live &= last_row > pos0 - window
-
-        @pl.when(live)
-        def _compute():
-            q = q_ref[...].reshape(rows, d).astype(jnp.float32)
-            m = jnp.full((rows,), NEG, jnp.float32)
-            l = jnp.zeros((rows,), jnp.float32)
-            acc = jnp.zeros((rows, d), jnp.float32)
-            cols0 = jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-            # per-draft-row cache positions: row (ti, gi) sits at pos0 + ti
-            tpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)
-            for j in range(c):       # unrolled: C table-resolved page loads
-                lp = logical_page(si, j)
-                pp = bt_ref[0, lp]   # physical page (the table gather)
-                kj = pl.load(k_ref, (pl.dslice(pp, 1), slice(None),
-                                     slice(None), slice(None))
-                             ).reshape(ps, d).astype(jnp.float32)
-                vj = pl.load(v_ref, (pl.dslice(pp, 1), slice(None),
-                                     slice(None), slice(None))
-                             ).reshape(ps, d).astype(jnp.float32)
-                if quant:
-                    kj = kj * pl.load(
-                        ks_ref, (pl.dslice(pp, 1), slice(None), slice(None))
-                    ).reshape(ps, 1)
-                    vj = vj * pl.load(
-                        vs_ref, (pl.dslice(pp, 1), slice(None), slice(None))
-                    ).reshape(ps, 1)
-                cols = cols0 + lp * ps                     # (1, ps)
-                maskt = cols <= tpos                       # (t, ps)
-                if window is not None:
-                    maskt &= cols > tpos - window
-                mask = jnp.broadcast_to(maskt[:, None, :],
-                                        (t, g, ps)).reshape(rows, ps)
-                sij = jnp.dot(q, kj.T,
-                              preferred_element_type=jnp.float32) * scale
-                sij = jnp.where(mask, sij, NEG)
-                m_new = jnp.maximum(m, sij.max(axis=1))
-                p = jnp.exp(sij - m_new[:, None]) * mask
-                alpha = jnp.exp(m - m_new)
-                l = l * alpha + p.sum(axis=1)
-                acc = acc * alpha[:, None] + jnp.dot(
-                    p, vj, preferred_element_type=jnp.float32)
-                m = m_new
-            m_ref[...] = m.reshape(m_ref.shape)
-            l_ref[...] = l.reshape(l_ref.shape)
-            acc_ref[...] = acc.reshape(acc_ref.shape)
-
-        @pl.when(jnp.logical_not(live))
-        def _dead():
-            m_ref[...] = jnp.full_like(m_ref, NEG)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    pool_spec = pl.BlockSpec((n_pages, ps, 1, d),
-                             lambda bb, hh, si: (0, 0, hh, 0))
-    sc_pool_spec = pl.BlockSpec((n_pages, ps, 1),
-                                lambda bb, hh, si: (0, 0, hh))
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda bb, hh, si: (bb, 0)),          # pos0
-        pl.BlockSpec((1, npp), lambda bb, hh, si: (bb, 0)),        # table
-        pl.BlockSpec((1, 1, rows, d), lambda bb, hh, si: (bb, hh, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    if quant:
-        in_specs += [sc_pool_spec, sc_pool_spec]
-
-    call = pl.pallas_call(
-        body,
-        grid=(b, hkv, n_splits),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, 1, rows, 1), lambda bb, hh, si: (bb, hh, 0, si)),
-            pl.BlockSpec((1, 1, rows, 1), lambda bb, hh, si: (bb, hh, 0, si)),
-            pl.BlockSpec((1, 1, rows, 1, d),
-                         lambda bb, hh, si: (bb, hh, 0, si, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, hkv, rows, n_splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, rows, n_splits), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, rows, n_splits, d), jnp.float32),
-        ),
-        interpret=interpret,
-    )
-
-    def _qview(q):
-        # (B,T,H,D) -> (B,Hkv,T*G,D), rows (ti, gi) flattened t-major so the
-        # per-page mask broadcast above lines up
-        return q.reshape(b, t, hkv, g, d).transpose(0, 2, 1, 3, 4) \
-                .reshape(b, hkv, rows, d)
-
-    def _oview(out, dtype):
-        # combined (B,Hkv,T*G,D) -> (B,T,H,D)
+    def run(q, *args):
+        *pools, block_table, pos0 = args
+        # (B,T,H,D) -> (B,Hkv,T*G,D), rows (ti, gi) flattened t-major
+        qv = q.reshape(b, t, hkv, g, d).transpose(0, 2, 1, 3, 4) \
+              .reshape(b, hkv, t * g, d)
+        out = _combine_splits(*call(pos0, block_table, qv, *pools))
         return out.reshape(b, hkv, t, g, d).transpose(0, 2, 1, 3, 4) \
-                  .reshape(b, t, h, d).astype(dtype)
-
-    if quant:
-        def run(q, k_pool, v_pool, k_scale, v_scale, block_table, pos0):
-            pos2 = pos0.reshape(b, 1).astype(jnp.int32)
-            bt = block_table.astype(jnp.int32)
-            m, l, acc = call(pos2, bt, _qview(q), k_pool, v_pool,
-                             k_scale, v_scale)
-            return _oview(_combine(m, l, acc), q.dtype)
-    else:
-        def run(q, k_pool, v_pool, block_table, pos0):
-            pos2 = pos0.reshape(b, 1).astype(jnp.int32)
-            bt = block_table.astype(jnp.int32)
-            m, l, acc = call(pos2, bt, _qview(q), k_pool, v_pool)
-            return _oview(_combine(m, l, acc), q.dtype)
+                  .reshape(b, t, h, d).astype(q.dtype)
 
     return run

@@ -60,27 +60,52 @@ def _check_geometry(sq: int, sk: int, causal: bool, window) -> None:
 def _q_axis_layout(b: int, h: int, sq: int, d: int, c: int, bq: int,
                    gapped: bool):
     """BlockSpecs + array views for the q-row-coarsened kernels (forward
-    and dQ): the (C*bq, D) q/do/dq tiles and the (C*bq,) residual rows.
-    The gapped view (C, Sq/C) is a pure reshape of row order, so residual
+    and dQ): the (C*bq, D) q/do/dq tiles and the (C*bq, 1) residual rows.
+    Residuals travel as (..., Sq, 1) columns so every block's last two dims
+    are (8,128)-aligned or full, which the Mosaic lowering requires.  The
+    gapped view (C, Sq/C) is a pure reshape of row order, so residual
     arrays flatten back to (B, H, Sq) with rows in global order."""
     sg = sq // c
     if gapped:
         q_spec = pl.BlockSpec((1, 1, c, bq, d),
                               lambda bb, hh, qi, ki: (bb, hh, 0, qi, 0))
         q_view = lambda q: q.reshape(b, h, c, sg, d)
-        r_spec = pl.BlockSpec((1, 1, c, bq),
-                              lambda bb, hh, qi, ki: (bb, hh, 0, qi))
-        r_view = lambda r: r.reshape(b, h, c, sg)
-        o_shape, r_shape = (b, h, c, sg, d), (b, h, c, sg)
+        r_spec = pl.BlockSpec((1, 1, c, bq, 1),
+                              lambda bb, hh, qi, ki: (bb, hh, 0, qi, 0))
+        r_view = lambda r: r.reshape(b, h, c, sg, 1)
+        o_shape, r_shape = (b, h, c, sg, d), (b, h, c, sg, 1)
     else:
         q_spec = pl.BlockSpec((1, 1, c * bq, d),
                               lambda bb, hh, qi, ki: (bb, hh, qi, 0))
         q_view = lambda q: q
-        r_spec = pl.BlockSpec((1, 1, c * bq),
-                              lambda bb, hh, qi, ki: (bb, hh, qi))
-        r_view = lambda r: r
-        o_shape, r_shape = (b, h, sq, d), (b, h, sq)
+        r_spec = pl.BlockSpec((1, 1, c * bq, 1),
+                              lambda bb, hh, qi, ki: (bb, hh, qi, 0))
+        r_view = lambda r: r.reshape(b, h, sq, 1)
+        o_shape, r_shape = (b, h, sq, d), (b, h, sq, 1)
     return q_spec, q_view, r_spec, r_view, o_shape, r_shape
+
+
+def _fused_ids(axis_ids, p, *, c: int, blk: int, seg: int, gapped: bool):
+    """Global row (or column) ids of the C fused blocks of program ``p``:
+    ``axis_ids`` is a 2-D iota over the fused axis (0..C*blk-1).  A
+    consecutive program owns C adjacent blocks; a gapped one owns blocks
+    ``seg`` rows apart (2-D iotas only: Mosaic has no 1-D vector layout)."""
+    if not gapped:
+        return p * (c * blk) + axis_ids
+    return (jax.lax.div(axis_ids, blk) * seg + p * blk
+            + jax.lax.rem(axis_ids, blk))
+
+
+def _dot_nt(a, b):
+    """a (M,K) . b (N,K)^T -> (M,N) f32 without materialising b.T."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """a (K,M)^T . b (K,N) -> (M,N) f32 without materialising a.T."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _q_axis_mask_live(qi, ki, *, c: int, bq: int, bkv: int, sg: int,
@@ -92,20 +117,17 @@ def _q_axis_mask_live(qi, ki, *, c: int, bq: int, bkv: int, sg: int,
     blocks, a gapped program's rows span the sequence so the skip
     degenerates to the worst row (the divergence penalty)."""
     rows_per_prog = c * bq
-    j = jax.lax.broadcasted_iota(jnp.int32, (c, bq), 1)
-    k = jax.lax.broadcasted_iota(jnp.int32, (c, bq), 0)
-    if gapped:
-        rows = (k * sg + qi * bq + j).reshape(rows_per_prog)
-    else:
-        rows = (qi * rows_per_prog + k * bq + j).reshape(rows_per_prog)
-    cols = ki * bkv + jnp.arange(bkv, dtype=jnp.int32)
-    mask = jnp.ones((rows_per_prog, bkv), dtype=bool)
+    shape = (rows_per_prog, bkv)
+    rows = _fused_ids(jax.lax.broadcasted_iota(jnp.int32, shape, 0), qi,
+                      c=c, blk=bq, seg=sg, gapped=gapped)
+    cols = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mask = jnp.ones(shape, dtype=bool)
     if causal:
-        mask &= cols[None, :] <= rows[:, None]
+        mask &= cols <= rows
     if window is not None:
-        mask &= cols[None, :] > rows[:, None] - window
+        mask &= cols > rows - window
 
-    min_row = rows[0] if not gapped else qi * bq   # smallest fused row id
+    min_row = qi * bq if gapped else qi * rows_per_prog  # smallest fused row
     live = jnp.bool_(True)
     if causal:
         live = ki * bkv <= (min_row + rows_per_prog - 1 if not gapped
@@ -162,22 +184,23 @@ def make_kernel(b: int, h: int, hkv: int, s: int, d: int,
             q = q_ref[...].reshape(rows_per_prog, d)
             kk = k_ref[...].reshape(bkv, d)
             vv = v_ref[...].reshape(bkv, d)
-            sij = jnp.dot(q, kk.T, preferred_element_type=jnp.float32) * scale
+            sij = _dot_nt(q, kk) * scale
             sij = jnp.where(mask, sij, NEG)
             m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, sij.max(axis=1))
-            p = jnp.exp(sij - m_new[:, None]) * mask
+            m_new = jnp.maximum(m_prev, sij.max(axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(sij - m_new), 0.0)
             alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-            acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                            + jnp.dot(p, vv, preferred_element_type=jnp.float32))
+            l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+            acc_ref[...] = (acc_ref[...] * alpha
+                            + jnp.dot(p, vv.astype(jnp.float32),
+                                      preferred_element_type=jnp.float32))
             m_ref[...] = m_new
 
         @pl.when(ki == nk - 1)
         def _fin():
             l = l_ref[...]
             lg = jnp.where(l == 0.0, 1.0, l)
-            o_ref[...] = (acc_ref[...] / lg[:, None]).reshape(o_ref.shape)
+            o_ref[...] = (acc_ref[...] / lg).reshape(o_ref.shape)
             if return_residuals:
                 mo_ref[...] = m_ref[...].reshape(mo_ref.shape)
                 lo_ref[...] = l.reshape(lo_ref.shape)
@@ -204,8 +227,8 @@ def make_kernel(b: int, h: int, hkv: int, s: int, d: int,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((rows_per_prog,), jnp.float32),
-            pltpu.VMEM((rows_per_prog,), jnp.float32),
+            pltpu.VMEM((rows_per_prog, 1), jnp.float32),
+            pltpu.VMEM((rows_per_prog, 1), jnp.float32),
             pltpu.VMEM((rows_per_prog, d), jnp.float32),
         ],
         interpret=interpret,
@@ -266,17 +289,17 @@ def make_bwd_dq_kernel(b: int, h: int, hkv: int, s: int, d: int,
             kk = k_ref[...].reshape(bkv, d).astype(jnp.float32)
             vv = v_ref[...].reshape(bkv, d).astype(jnp.float32)
             do = do_ref[...].reshape(rows_per_prog, d).astype(jnp.float32)
-            m = m_ref[...].reshape(rows_per_prog)
-            l = l_ref[...].reshape(rows_per_prog)
+            m = m_ref[...].reshape(rows_per_prog, 1)
+            l = l_ref[...].reshape(rows_per_prog, 1)
             l = jnp.where(l == 0.0, 1.0, l)
-            dl = dl_ref[...].reshape(rows_per_prog)
-            sij = jnp.dot(q, kk.T, preferred_element_type=jnp.float32) * scale
+            dl = dl_ref[...].reshape(rows_per_prog, 1)
+            sij = _dot_nt(q, kk) * scale
             # flash-style recompute: p from the saved (m, l) residuals; the
             # double-where keeps masked entries at exp(NEG)~0 even when a
             # row's m is the NEG sentinel (fully-masked rows)
-            p = jnp.exp(jnp.where(mask, sij - m[:, None], NEG)) / l[:, None]
-            dp = jnp.dot(do, vv.T, preferred_element_type=jnp.float32)
-            ds = p * (dp - dl[:, None])
+            p = jnp.exp(jnp.where(mask, sij - m, NEG)) / l
+            dp = _dot_nt(do, vv)
+            ds = p * (dp - dl)
             acc_ref[...] += jnp.dot(ds, kk,
                                     preferred_element_type=jnp.float32) * scale
 
@@ -340,13 +363,6 @@ def make_bwd_dkv_kernel(b: int, h: int, hkv: int, s: int, d: int,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     cols_per_prog = c * bkv
 
-    def col_ids(ki):
-        j = jax.lax.broadcasted_iota(jnp.int32, (c, bkv), 1)
-        kb = jax.lax.broadcasted_iota(jnp.int32, (c, bkv), 0)
-        if gapped:
-            return (kb * skg + ki * bkv + j).reshape(cols_per_prog)
-        return (ki * cols_per_prog + kb * bkv + j).reshape(cols_per_prog)
-
     def body(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dl_ref,
              dk_ref, dv_ref, dk_s, dv_s):
         ki, qi = pl.program_id(2), pl.program_id(3)
@@ -356,13 +372,15 @@ def make_bwd_dkv_kernel(b: int, h: int, hkv: int, s: int, d: int,
             dk_s[...] = jnp.zeros_like(dk_s)
             dv_s[...] = jnp.zeros_like(dv_s)
 
-        cols = col_ids(ki)                             # (C*bkv,)
-        rows = qi * bq + jnp.arange(bq, dtype=jnp.int32)
-        mask = jnp.ones((bq, cols_per_prog), dtype=bool)
+        shape = (bq, cols_per_prog)
+        cols = _fused_ids(jax.lax.broadcasted_iota(jnp.int32, shape, 1), ki,
+                          c=c, blk=bkv, seg=skg, gapped=gapped)
+        rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        mask = jnp.ones(shape, dtype=bool)
         if causal:
-            mask &= cols[None, :] <= rows[:, None]
+            mask &= cols <= rows
         if window is not None:
-            mask &= cols[None, :] > rows[:, None] - window
+            mask &= cols > rows - window
 
         # causal skip: prune q blocks strictly before every fused kv row.
         # consecutive: min fused col = ki*C*bkv keeps ~half the sweep pruned;
@@ -383,17 +401,16 @@ def make_bwd_dkv_kernel(b: int, h: int, hkv: int, s: int, d: int,
             kk = k_ref[...].reshape(cols_per_prog, d).astype(jnp.float32)
             vv = v_ref[...].reshape(cols_per_prog, d).astype(jnp.float32)
             do = do_ref[...].reshape(bq, d).astype(jnp.float32)
-            m = m_ref[...].reshape(bq)
-            l = l_ref[...].reshape(bq)
+            m = m_ref[...].reshape(bq, 1)
+            l = l_ref[...].reshape(bq, 1)
             l = jnp.where(l == 0.0, 1.0, l)
-            dl = dl_ref[...].reshape(bq)
-            sij = jnp.dot(q, kk.T, preferred_element_type=jnp.float32) * scale
-            p = jnp.exp(jnp.where(mask, sij - m[:, None], NEG)) / l[:, None]
-            dv_s[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-            dp = jnp.dot(do, vv.T, preferred_element_type=jnp.float32)
-            ds = p * (dp - dl[:, None])
-            dk_s[...] += jnp.dot(ds.T, q,
-                                 preferred_element_type=jnp.float32) * scale
+            dl = dl_ref[...].reshape(bq, 1)
+            sij = _dot_nt(q, kk) * scale
+            p = jnp.exp(jnp.where(mask, sij - m, NEG)) / l
+            dv_s[...] += _dot_tn(p, do)
+            dp = _dot_nt(do, vv)
+            ds = p * (dp - dl)
+            dk_s[...] += _dot_tn(ds, q) * scale
 
         @pl.when(qi == nq - 1)
         def _fin():
@@ -416,7 +433,9 @@ def make_bwd_dkv_kernel(b: int, h: int, hkv: int, s: int, d: int,
         dkv_shape = (b, h, sk, d)
 
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda bb, hh, ki, qi: (bb, hh, qi, 0))
-    r_spec = pl.BlockSpec((1, 1, bq), lambda bb, hh, ki, qi: (bb, hh, qi))
+    r_spec = pl.BlockSpec((1, 1, bq, 1),
+                          lambda bb, hh, ki, qi: (bb, hh, qi, 0))
+    r_view = lambda r: r.reshape(b, h, sq, 1)
 
     call = pl.pallas_call(
         body,
@@ -439,7 +458,8 @@ def make_bwd_dkv_kernel(b: int, h: int, hkv: int, s: int, d: int,
     )
 
     def run(q, k, v, do, m, l, delta):
-        dkh, dvh = call(q, kv_view(k), kv_view(v), do, m, l, delta)
+        dkh, dvh = call(q, kv_view(k), kv_view(v), do, r_view(m), r_view(l),
+                        r_view(delta))
         dkh = dkh.reshape(b, h, sk, d)
         dvh = dvh.reshape(b, h, sk, d)
         # GQA: reduce per-q-head partials onto the shared kv heads

@@ -461,10 +461,9 @@ def _paged_decode_fn(b, h, hkv, n_pages, npp, d, cfg, page_size, window,
         def run(q, k_pool, v_pool, bt, pos):
             # gather-to-contiguous oracle: resolve the block table on the
             # host-visible (XLA) side, then dense full-length attention
-            k = k_pool[bt].reshape(b, npp * page_size, hkv, d)
-            v = v_pool[bt].reshape(b, npp * page_size, hkv, d)
-            return ref.decode_attention(q, k, v, pos, window=window,
-                                        scale=scale)
+            return ref.decode_attention(q, ref.paged_view(k_pool, bt),
+                                        ref.paged_view(v_pool, bt), pos,
+                                        window=window, scale=scale)
         return jax.jit(run)
     return jax.jit(_decode.make_paged_kernel(b, h, hkv, n_pages, npp, d, cfg,
                                              page_size=page_size,
@@ -481,16 +480,16 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos,
                            k_scale=None, v_scale=None):
     """Split-KV decode attention through a per-slot block table.
 
-    q: (B,1,H,D); pools: (P, page_size, Hkv, D) shared by all slots;
+    q: (B,1,H,D); pools: (P, Hkv, page_size, D) shared by all slots;
     block_table: (B, npp) int32 logical->physical page map (NULL-padded);
     pos: (B,) int32 -> (B,1,H,D).  The coarsening axis is the LOGICAL-PAGE
     axis (each program owns cfg.degree pages, resolved through the table —
     the gapped strided-pane DMA with the stride replaced by a lookup).
 
-    ``k_scale``/``v_scale`` (P, page_size, Hkv) select the int8 pool mode
+    ``k_scale``/``v_scale`` (P, Hkv, page_size) select the int8 pool mode
     (kv_bits=8 joins the tuner key, as does the page size)."""
     b, _, h, d = q.shape
-    n_pages, page_size, hkv, _ = k_pool.shape
+    n_pages, hkv, page_size, _ = k_pool.shape
     npp = block_table.shape[1]
     quant = k_scale is not None
     params = dict(page_size=page_size, window=window or 0)
@@ -519,10 +518,9 @@ def _verify_fn(b, h, hkv, t, n_pages, npp, d, cfg, page_size, window,
             # gather-to-contiguous oracle, same shape as the paged-decode
             # ref path: resolve the table on the XLA side, then the dense
             # per-row verify oracle
-            k = k_pool[bt].reshape(b, npp * page_size, hkv, d)
-            v = v_pool[bt].reshape(b, npp * page_size, hkv, d)
-            return ref.verify_attention(q, k, v, pos0, window=window,
-                                        scale=scale)
+            return ref.verify_attention(q, ref.paged_view(k_pool, bt),
+                                        ref.paged_view(v_pool, bt), pos0,
+                                        window=window, scale=scale)
         return jax.jit(run)
     return jax.jit(_decode.make_verify_kernel(b, h, hkv, t, n_pages, npp, d,
                                               cfg, page_size=page_size,
@@ -541,17 +539,17 @@ def flash_attention_verify(q, k_pool, v_pool, block_table, pos0,
     speculative-decode short-q flash geometry).
 
     q: (B,T,H,D) — T drafted rows per slot, row t at cache position
-    ``pos0[b] + t``; pools: (P, page_size, Hkv, D); block_table: (B, npp)
+    ``pos0[b] + t``; pools: (P, Hkv, page_size, D); block_table: (B, npp)
     int32; pos0: (B,) int32 -> (B,T,H,D).  The coarsening axis is the
     LOGICAL-PAGE axis as in `paged_decode_attention`, but the tuner family
     (``flash_attention_verify``) is distinct: scoring T*G rows per fetched
     page moves the memory/compute crossover, so the winning degree differs
     from both the decode and prefill families.
 
-    ``k_scale``/``v_scale`` (P, page_size, Hkv) select the int8 pool mode
+    ``k_scale``/``v_scale`` (P, Hkv, page_size) select the int8 pool mode
     (kv_bits=8 joins the tuner key)."""
     b, t, h, d = q.shape
-    n_pages, page_size, hkv, _ = k_pool.shape
+    n_pages, hkv, page_size, _ = k_pool.shape
     npp = block_table.shape[1]
     quant = k_scale is not None
     params = dict(page_size=page_size, window=window or 0)

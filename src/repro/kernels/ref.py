@@ -122,6 +122,18 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
+# --- paged pools -------------------------------------------------------------
+
+def paged_view(pool: jax.Array, block_table: jax.Array) -> jax.Array:
+    """Gather a page pool through a block table into the contiguous per-slot
+    view: pool (P, Hkv, page_size, ...) with block_table (B, npp) ->
+    (B, npp * page_size, Hkv, ...).  Serves K/V pools and their (P, Hkv,
+    page_size) scale pools alike."""
+    g = jnp.swapaxes(pool[block_table.astype(jnp.int32)], 2, 3)
+    b, npp, ps = g.shape[:3]
+    return g.reshape((b, npp * ps) + g.shape[3:])
+
+
 # --- verify attention (speculative decode: short q vs a long cache) ----------
 
 def verify_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

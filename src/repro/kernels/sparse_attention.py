@@ -45,6 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.coarsening import CoarseningConfig, KIND_GAPPED
+from repro.kernels.flash_attention import _dot_nt
 
 NEG = -1e30
 
@@ -224,7 +225,7 @@ def make_kernel(b: int, h: int, hkv: int, s: int, d: int,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        rows = qi * bq + jnp.arange(bq, dtype=jnp.int32)
+        rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         cols0 = jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
         for j in range(c):             # unrolled: C index-resolved slots
             slot = (j * seg + si) if gapped else (si * c + j)
@@ -233,26 +234,21 @@ def make_kernel(b: int, h: int, hkv: int, s: int, d: int,
             @pl.when(lb >= 0)          # NULL slot: no DMA, no compute
             def _slot(lb=lb):
                 q = q_ref[...].reshape(bq, d)
-                kk = pl.load(k_ref, (slice(None), slice(None),
-                                     pl.dslice(lb, 1), slice(None),
-                                     slice(None))).reshape(bkv, d)
-                vv = pl.load(v_ref, (slice(None), slice(None),
-                                     pl.dslice(lb, 1), slice(None),
-                                     slice(None))).reshape(bkv, d)
+                kk = k_ref[0, 0, pl.ds(lb, 1)].reshape(bkv, d)
+                vv = v_ref[0, 0, pl.ds(lb, 1)].reshape(bkv, d)
                 cols = cols0 + lb * bkv                        # (1, bkv)
-                mask = _element_mask(rows[:, None], cols, causal=causal,
+                mask = _element_mask(rows, cols, causal=causal,
                                      window=window,
                                      global_stride=global_stride)
-                sij = jnp.dot(q, kk.T,
-                              preferred_element_type=jnp.float32) * scale
+                sij = _dot_nt(q, kk) * scale
                 sij = jnp.where(mask, sij, NEG)
                 m_prev = m_ref[...]
-                m_new = jnp.maximum(m_prev, sij.max(axis=1))
-                p = jnp.exp(sij - m_new[:, None]) * mask
+                m_new = jnp.maximum(m_prev, sij.max(axis=1, keepdims=True))
+                p = jnp.where(mask, jnp.exp(sij - m_new), 0.0)
                 alpha = jnp.exp(m_prev - m_new)
-                l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-                acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                                + jnp.dot(p, vv,
+                l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+                acc_ref[...] = (acc_ref[...] * alpha
+                                + jnp.dot(p, vv.astype(jnp.float32),
                                           preferred_element_type=jnp.float32))
                 m_ref[...] = m_new
 
@@ -260,7 +256,7 @@ def make_kernel(b: int, h: int, hkv: int, s: int, d: int,
         def _fin():
             l = l_ref[...]
             lg = jnp.where(l == 0.0, 1.0, l)
-            o_ref[...] = (acc_ref[...] / lg[:, None]).reshape(o_ref.shape)
+            o_ref[...] = (acc_ref[...] / lg).reshape(o_ref.shape)
             if return_residuals:
                 mo_ref[...] = m_ref[...].reshape(mo_ref.shape)
                 lo_ref[...] = l.reshape(lo_ref.shape)
@@ -274,13 +270,14 @@ def make_kernel(b: int, h: int, hkv: int, s: int, d: int,
                           lambda bb, hh, qi, si: (bb, hh, qi, 0))
     kv_spec = pl.BlockSpec((1, 1, nkb, bkv, d),
                            lambda bb, hh, qi, si: (bb, hh // group, 0, 0, 0))
-    r_spec = pl.BlockSpec((1, 1, bq), lambda bb, hh, qi, si: (bb, hh, qi))
+    r_spec = pl.BlockSpec((1, 1, bq, 1),
+                          lambda bb, hh, qi, si: (bb, hh, qi, 0))
 
     out_specs = (q_spec, r_spec, r_spec) if return_residuals else q_spec
     out_shape = (
         (jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32),
-         jax.ShapeDtypeStruct((b, h, sq), jnp.float32),
-         jax.ShapeDtypeStruct((b, h, sq), jnp.float32))
+         jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
+         jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32))
         if return_residuals
         else jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32))
 
@@ -296,8 +293,8 @@ def make_kernel(b: int, h: int, hkv: int, s: int, d: int,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
@@ -308,6 +305,7 @@ def make_kernel(b: int, h: int, hkv: int, s: int, d: int,
         out = call(idx, q, kv(k), kv(v))
         if not return_residuals:
             return out
-        return out                     # (o, m, l), already in global order
+        o, m, l = out                  # already in global row order
+        return o, m.reshape(b, h, sq), l.reshape(b, h, sq)
 
     return run

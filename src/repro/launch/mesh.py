@@ -11,6 +11,16 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """A device mesh whose axes are all Auto: the compiler propagates
+    shardings from the jit in/out shardings and constraints, which is what
+    the train step, the shard_map paths and the checkpoint restore are
+    written against (``jax.make_mesh`` alone gives Explicit axes)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,4 +32,4 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {need} devices, have {len(devices)}; launch through "
             f"launch/dryrun.py (it forces 512 host devices) or a real fleet")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return make_mesh(shape, axes, devices=devices[:need])

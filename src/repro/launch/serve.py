@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models.config import ModelConfig
 
@@ -89,6 +90,12 @@ def _slot_reset(cache, slot):
             lambda a: a.at[slot].set(jnp.zeros((), a.dtype)), c)
             for c in cache["tail"]],
     }
+
+
+def device_name() -> str:
+    """What the tok/s lines were measured on: platform, kind and count."""
+    devs = jax.devices()
+    return f"{devs[0].platform} {devs[0].device_kind} x{len(devs)}"
 
 
 def _tree_mib(tree) -> float:
@@ -370,7 +377,7 @@ def _serve_paged(args, cfg, params, rng) -> None:
           f"({engine.prefill_tokens / max(pdev, 1e-9):.1f} tok/s)"
           f" | decode: {engine.decoded_tokens} tok in {ddev:.2f}s device "
           f"({engine.decoded_tokens / max(ddev, 1e-9):.1f} tok/s)"
-          f" (CPU interpret-scale)")
+          f" ({device_name()})")
     ovh = max(dt - pdev - ddev, 0.0)
     print(f"overhead: scheduler+host {ovh:.2f}s of {dt:.2f}s wall "
           f"({ovh / max(dt, 1e-9):.0%})")
@@ -407,7 +414,11 @@ def _serve_paged(args, cfg, params, rng) -> None:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b", choices=ARCHS)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the tiny same-family config (the default, "
+                         "sized for CPU); --no-reduced serves the published "
+                         "widths")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -506,6 +517,7 @@ def main():
                  "paged (the recorder hooks live in the scheduler/paged-"
                  "engine stack)")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -549,7 +561,7 @@ def main():
           f"({server.prefill_tokens / max(pdev, 1e-9):.1f} tok/s)"
           f" | decode: {server.decoded_tokens} tok in {ddev:.2f}s device "
           f"({server.decoded_tokens / max(ddev, 1e-9):.1f} tok/s)"
-          f" (CPU interpret-scale)")
+          f" ({device_name()})")
     ovh = max(dt - pdev - ddev, 0.0)
     print(f"overhead: driver+host {ovh:.2f}s of {dt:.2f}s wall "
           f"({ovh / max(dt, 1e-9):.0%})")

@@ -29,12 +29,18 @@ from repro.checkpoint import CheckpointManager
 from repro.runtime import StepWatchdog, PreemptionHandler, retry_step
 from repro.distributed.sharding import (param_shardings, batch_specs,
                                         make_shard_ctx)
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import StepConfig, build_train_step
 
 
-def make_mesh_for_host():
-    devs = jax.devices()
-    return jax.make_mesh((len(devs), 1), ("data", "model"))
+def make_mesh_for_host(model: int = 1):
+    """(data, model) mesh over this host's devices: ``model``-way tensor
+    parallel, the rest data parallel."""
+    n = len(jax.devices())
+    if n % model:
+        raise ValueError(f"{n} devices do not split {model}-way")
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def train(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
@@ -195,6 +201,7 @@ def main():
                          "f32 on a held-out batch")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

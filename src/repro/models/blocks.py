@@ -184,13 +184,14 @@ def attn_cache_init(cfg: ModelConfig, b: int, s_max: int, dtype=jnp.bfloat16):
 
 def attn_cache_init_paged(cfg: ModelConfig, num_pages: int, page_size: int,
                           dtype=jnp.bfloat16):
-    """Paged decode K/V: one global (P, page_size, Hkv, hd) pool per layer,
+    """Paged decode K/V: one global (P, Hkv, page_size, hd) pool per layer,
     shared by every slot through its block table.  Page 0 is the NULL page
     (repro.serve.paging): never allocated, and the write paths route
     inactive slots' scatters to it.  cfg.kv_quant="int8" composes — int8
-    payload pools + per-(row, kv-head) f32 scale pools, double the pages
-    per HBM byte."""
-    shape = (num_pages, page_size, cfg.n_kv_heads, cfg.hd)
+    payload pools + per-(kv-head, row) f32 scale pools, double the pages
+    per HBM byte.  Head-major pages make each (page, kv head) a contiguous
+    (page_size, hd) tile: the unit the paged kernel DMAs."""
+    shape = (num_pages, cfg.n_kv_heads, page_size, cfg.hd)
     if cfg.kv_quant == "int8":
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
@@ -208,13 +209,19 @@ def _paged_rows(block_table, pos, page_size):
     return block_table[bidx, pos // page_size], pos % page_size
 
 
+def _pool_set(pool, page, row, val):
+    """Write per-row values (pos.shape + (Hkv, ...)) into a head-major pool
+    (P, Hkv, ps, ...) at physical (page, row)."""
+    return pool.at[page, :, row].set(val)
+
+
 def attn_block_decode_paged(p, x, cfg: ModelConfig, cache, *, kind: str, pos,
                             block_table, write_mask=None,
                             shard: ShardCtx = NOSHARD):
     """Paged twin of attn_block_decode: the new row scatters through the
     block table into the shared pool and attention reads the pool through
     the same table.  cache: {'k','v'[,'k_scale','v_scale']} pools
-    (P,ps,kv,hd); block_table: (B, npp) int32; pos: (B,).
+    (P,kv,ps,hd); block_table: (B, npp) int32; pos: (B,).
 
     ``write_mask`` (B,) bool suppresses a slot's cache write (the
     speculative draft scan pads every slot to the batch-max draft length;
@@ -222,7 +229,7 @@ def attn_block_decode_paged(p, x, cfg: ModelConfig, cache, *, kind: str, pos,
     table lookup CLAMPS and would alias a live page — so the page is routed
     to NULL before the scatter)."""
     window = cfg.window if kind == ATTN_LOCAL else None
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
     q, k, v = L.qkv_project(p["attn"], h, cfg, pos[:, None])
     quant = "k_scale" in cache
@@ -235,10 +242,10 @@ def attn_block_decode_paged(p, x, cfg: ModelConfig, cache, *, kind: str, pos,
         from repro.quant.qtypes import quantize_kv
         k_upd, ks_new = quantize_kv(k_upd.astype(jnp.float32))
         v_upd, vs_new = quantize_kv(v_upd.astype(jnp.float32))
-        kscale = cache["k_scale"].at[page, row].set(ks_new)
-        vscale = cache["v_scale"].at[page, row].set(vs_new)
-    kc = cache["k"].at[page, row].set(k_upd)
-    vc = cache["v"].at[page, row].set(v_upd)
+        kscale = _pool_set(cache["k_scale"], page, row, ks_new)
+        vscale = _pool_set(cache["v_scale"], page, row, vs_new)
+    kc = _pool_set(cache["k"], page, row, k_upd)
+    vc = _pool_set(cache["v"], page, row, v_upd)
     o = L.paged_decode_attention(q, kc, vc, block_table, pos, window=window,
                                  backend=cfg.decode_backend,
                                  cfg=cfg.decode_attn_cfg,
@@ -265,8 +272,7 @@ def attn_block_prefill_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
     protection for non-admitted slots comes from the table itself — the
     engine nulls their rows, so their scatters land on the null page."""
     b, t, _ = x.shape
-    ps = cache["k"].shape[1]
-    npp = block_table.shape[1]
+    ps = cache["k"].shape[2]
     window = cfg.window if kind == ATTN_LOCAL else None
     pos = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None]     # (B,T)
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
@@ -279,21 +285,20 @@ def attn_block_prefill_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
         kq, ks_new = quantize_kv(k.astype(jnp.float32))
         vq, vs_new = quantize_kv(v.astype(jnp.float32))
         k_upd, v_upd = jax.lax.optimization_barrier((kq, vq))
-        newc["k_scale"] = cache["k_scale"].at[page, row].set(ks_new)
-        newc["v_scale"] = cache["v_scale"].at[page, row].set(vs_new)
+        newc["k_scale"] = _pool_set(cache["k_scale"], page, row, ks_new)
+        newc["v_scale"] = _pool_set(cache["v_scale"], page, row, vs_new)
     else:
         k_upd, v_upd = jax.lax.optimization_barrier(
             (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)))
-    kc = cache["k"].at[page, row].set(k_upd)
-    vc = cache["v"].at[page, row].set(v_upd)
-    bt = block_table.astype(jnp.int32)
-    ka = kc[bt].reshape(b, npp * ps, cfg.n_kv_heads, cfg.hd)
-    va = vc[bt].reshape(b, npp * ps, cfg.n_kv_heads, cfg.hd)
+    kc = _pool_set(cache["k"], page, row, k_upd)
+    vc = _pool_set(cache["v"], page, row, v_upd)
+    from repro.kernels.ref import paged_view
+    ka, va = paged_view(kc, block_table), paged_view(vc, block_table)
     if quant:
         from repro.quant.qtypes import dequantize_kv
-        ka = dequantize_kv(ka, newc["k_scale"][bt].reshape(b, npp * ps, -1)
+        ka = dequantize_kv(ka, paged_view(newc["k_scale"], block_table)
                            ).astype(x.dtype)
-        va = dequantize_kv(va, newc["v_scale"][bt].reshape(b, npp * ps, -1)
+        va = dequantize_kv(va, paged_view(newc["v_scale"], block_table)
                            ).astype(x.dtype)
     o = L.flash_attention(q, ka, va, causal=True, window=window, q_pos=pos,
                           **_attn_kw(cfg))
@@ -322,7 +327,7 @@ def attn_block_verify_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
     route the page to NULL before the scatter instead (scatters to row 0
     of the null page are harmless by construction)."""
     b, t, _ = x.shape
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     window = cfg.window if kind == ATTN_LOCAL else None
     pos = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None]     # (B,T)
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
@@ -338,13 +343,13 @@ def attn_block_verify_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
         kq, ks_new = quantize_kv(k.astype(jnp.float32))
         vq, vs_new = quantize_kv(v.astype(jnp.float32))
         k_upd, v_upd = jax.lax.optimization_barrier((kq, vq))
-        kscale = cache["k_scale"].at[page, row].set(ks_new)
-        vscale = cache["v_scale"].at[page, row].set(vs_new)
+        kscale = _pool_set(cache["k_scale"], page, row, ks_new)
+        vscale = _pool_set(cache["v_scale"], page, row, vs_new)
     else:
         k_upd, v_upd = jax.lax.optimization_barrier(
             (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)))
-    kc = cache["k"].at[page, row].set(k_upd)
-    vc = cache["v"].at[page, row].set(v_upd)
+    kc = _pool_set(cache["k"], page, row, k_upd)
+    vc = _pool_set(cache["v"], page, row, v_upd)
     o = L.verify_attention(q, kc, vc, block_table, pos0, window=window,
                            backend=cfg.decode_backend,
                            cfg=cfg.decode_attn_cfg,

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels import ref as KREF
 from repro.models.config import ModelConfig
 from repro.quant.qtypes import QTensor, asdense, dequantize, dequantize_kv
 
@@ -458,7 +459,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, *,
                            window=None, scale=None, backend: str = "ref",
                            cfg="auto", k_scale=None, v_scale=None):
     """Single-token attention against a PAGED cache.  q: (B,1,H,D);
-    pools: (P, page_size, Hkv, D) shared by every slot; block_table:
+    pools: (P, Hkv, page_size, D) shared by every slot; block_table:
     (B, npp) int32 per-slot logical->physical page map; pos: (B,).
 
     backend="pallas" dispatches the block-table split-KV kernel
@@ -468,12 +469,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, *,
     contiguous per-slot view and runs the dense einsum path — which is also
     the parity oracle the paged kernel is tested against.
 
-    ``k_scale``/``v_scale`` (P, page_size, Hkv) mark int8 pools
+    ``k_scale``/``v_scale`` (P, Hkv, page_size) mark int8 pools
     (cfg.kv_quant="int8"): dequant is fused into the kernel pass; the
     fallback dequantizes the gathered view first.
     """
     b, _, h, d = q.shape
-    n_pages, ps, hkv, _ = k_pool.shape
+    n_pages, hkv, ps, _ = k_pool.shape
     npp = block_table.shape[1]
     if backend == "pallas" and h % hkv == 0:
         from repro.kernels import ops
@@ -490,13 +491,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, *,
                 q, k_pool, v_pool, block_table, pos, rcfg, window=window,
                 scale=scale, k_scale=k_scale, v_scale=v_scale)
     # gather-to-contiguous fallback (and the paged kernel's parity oracle)
-    bt = block_table.astype(jnp.int32)
-    k_view = k_pool[bt].reshape(b, npp * ps, hkv, d)
-    v_view = v_pool[bt].reshape(b, npp * ps, hkv, d)
+    k_view = KREF.paged_view(k_pool, block_table)
+    v_view = KREF.paged_view(v_pool, block_table)
     ks = vs = None
     if k_scale is not None:
-        ks = k_scale[bt].reshape(b, npp * ps, hkv)
-        vs = v_scale[bt].reshape(b, npp * ps, hkv)
+        ks = KREF.paged_view(k_scale, block_table)
+        vs = KREF.paged_view(v_scale, block_table)
     return decode_attention(q, k_view, v_view, pos, window=window,
                             scale=scale, backend="ref",
                             k_scale=ks, v_scale=vs)
@@ -520,7 +520,7 @@ def verify_attention(q, k_pool, v_pool, block_table, pos0, *, window=None,
     sequential decode on the ref backend.
     """
     b, t, h, d = q.shape
-    n_pages, ps, hkv, _ = k_pool.shape
+    n_pages, hkv, ps, _ = k_pool.shape
     npp = block_table.shape[1]
     if backend == "pallas" and h % hkv == 0:
         from repro.kernels import ops
@@ -537,12 +537,11 @@ def verify_attention(q, k_pool, v_pool, block_table, pos0, *, window=None,
                 q, k_pool, v_pool, block_table, pos0, rcfg, window=window,
                 scale=scale, k_scale=k_scale, v_scale=v_scale)
     # gather-to-contiguous fallback (and the verify kernel's parity oracle)
-    bt = block_table.astype(jnp.int32)
-    k_view = k_pool[bt].reshape(b, npp * ps, hkv, d)
-    v_view = v_pool[bt].reshape(b, npp * ps, hkv, d)
+    k_view = KREF.paged_view(k_pool, block_table)
+    v_view = KREF.paged_view(v_pool, block_table)
     if k_scale is not None:
-        k_view = dequantize_kv(k_view, k_scale[bt].reshape(b, npp * ps, hkv))
-        v_view = dequantize_kv(v_view, v_scale[bt].reshape(b, npp * ps, hkv))
+        k_view = dequantize_kv(k_view, KREF.paged_view(k_scale, block_table))
+        v_view = dequantize_kv(v_view, KREF.paged_view(v_scale, block_table))
     s = npp * ps
     g = h // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -805,7 +804,6 @@ def _moe_shardmap(p, x, cfg: ModelConfig, *, capacity, renorm,
                   shard: ShardCtx):
     """Expert-parallel MoE via shard_map (see `moe` docstring)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b, s, d = x.shape
     t = b * s
@@ -857,12 +855,12 @@ def _moe_shardmap(p, x, cfg: ModelConfig, *, capacity, renorm,
         y_l = lax.psum(y_l.astype(jnp.dtype(cfg.moe_combine_dtype)), tp_axis)
         return y_l.astype(xt_l.dtype), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=shard.mesh,
         in_specs=(P(dp, None), P(), P(tp_axis, None, None),
                   P(tp_axis, None, None), P(tp_axis, None, None)),
         out_specs=(P(dp, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(xt, router, w1, w3, w2)
 
     if cfg.n_shared_experts:

@@ -376,7 +376,7 @@ def lm_init_cache(cfg: ModelConfig, b: int, s_max: int, dtype=jnp.bfloat16,
 def lm_init_cache_paged(cfg: ModelConfig, b: int, num_pages: int,
                         page_size: int, dtype=jnp.bfloat16):
     """Paged decode cache: attention K/V lives in GLOBAL page pools shared
-    by every slot (stacked (n_periods, P, page_size, kv, hd) leaves — no
+    by every slot (stacked (n_periods, P, kv, page_size, hd) leaves — no
     batch axis; a slot's rows are reached through its block table), while
     recurrent/SSM state keeps the per-slot batch layout.  Every attention
     layer shares ONE page id space: page p means row p of each layer's

@@ -115,8 +115,10 @@ class PagePool:
             self.refcount[p] += 1
 
     def release(self, pages) -> None:
-        """Decref; a page returns to the free list exactly at refcount 0."""
-        for p in pages:
+        """Decref; a page returns to the free list exactly at refcount 0.
+        Pages go back in reverse order, so releasing what one ``alloc``
+        returned restores the free list exactly."""
+        for p in reversed(list(pages)):
             if p == NULL_PAGE:
                 continue
             if self.refcount[p] <= 0:

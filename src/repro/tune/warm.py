@@ -374,9 +374,9 @@ def wall_measurer(reps: int = 3):
             n_pages = b * npp + 1
             q = jax.random.normal(key, (b, 1, h, d), dt)
             kp = jax.random.normal(jax.random.fold_in(key, 1),
-                                   (n_pages, ps, hkv, d), dt)
+                                   (n_pages, hkv, ps, d), dt)
             vp = jax.random.normal(jax.random.fold_in(key, 2),
-                                   (n_pages, ps, hkv, d), dt)
+                                   (n_pages, hkv, ps, d), dt)
             bt = jnp.asarray(jax.random.permutation(
                 jax.random.fold_in(key, 3),
                 jnp.arange(1, n_pages)).reshape(b, npp), jnp.int32)
@@ -401,9 +401,9 @@ def wall_measurer(reps: int = 3):
             n_pages = b * npp + 1
             q = jax.random.normal(key, (b, t, h, d), dt)
             kp = jax.random.normal(jax.random.fold_in(key, 1),
-                                   (n_pages, ps, hkv, d), dt)
+                                   (n_pages, hkv, ps, d), dt)
             vp = jax.random.normal(jax.random.fold_in(key, 2),
-                                   (n_pages, ps, hkv, d), dt)
+                                   (n_pages, hkv, ps, d), dt)
             bt = jnp.asarray(jax.random.permutation(
                 jax.random.fold_in(key, 3),
                 jnp.arange(1, n_pages)).reshape(b, npp), jnp.int32)

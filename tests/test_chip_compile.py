@@ -1,0 +1,105 @@
+"""Compile the smoke path's kernels for a described TPU v5e, at qwen3-0.6b
+widths (H=16, Hkv=8, D=128), without a chip: the TPU compiler refuses here
+what interpret mode accepts (unaligned blocks, 1-D vectors, too much VMEM).
+Each test asserts the compiled program holds the Mosaic kernel.
+
+The topology is described inside a module fixture, never at import time:
+only one process may load the TPU library, and every xdist worker imports
+this file."""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import CoarseningConfig
+from repro.kernels import decode_attention, flash_attention, matmul
+
+H, HKV, D = 16, 8, 128
+SLOTS, PAGE, NPP = 8, 16, 128           # 8 slots x max_len 2048, page 16
+N_PAGES = SLOTS * NPP + 1
+SEQ = 2048
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a compile for a described chip can be written to the persistent cache
+    # but not read back without one: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _cfg(label):
+    return CoarseningConfig.parse(label) if label != "con1" \
+        else CoarseningConfig()
+
+
+@pytest.mark.parametrize("spec", ["con1", "con4"])
+def test_paged_decode_compiles(one_chip, spec):
+    run = decode_attention.make_paged_kernel(
+        SLOTS, H, HKV, N_PAGES, NPP, D, _cfg(spec), page_size=PAGE,
+        interpret=False)
+    pool = ((N_PAGES, HKV, PAGE, D), jnp.bfloat16)
+    _compile(run, one_chip, ((SLOTS, 1, H, D), jnp.bfloat16), pool, pool,
+             ((SLOTS, NPP), jnp.int32), ((SLOTS,), jnp.int32))
+
+
+def test_paged_verify_compiles(one_chip):
+    t = 5
+    run = decode_attention.make_verify_kernel(
+        SLOTS, H, HKV, t, N_PAGES, NPP, D, _cfg("con4"), page_size=PAGE,
+        interpret=False)
+    pool = ((N_PAGES, HKV, PAGE, D), jnp.bfloat16)
+    _compile(run, one_chip, ((SLOTS, t, H, D), jnp.bfloat16), pool, pool,
+             ((SLOTS, NPP), jnp.int32), ((SLOTS,), jnp.int32))
+
+
+Q = ((1, H, SEQ, D), jnp.bfloat16)
+KV = ((1, HKV, SEQ, D), jnp.bfloat16)
+DO = ((1, H, SEQ, D), jnp.float32)
+RES = ((1, H, SEQ), jnp.float32)
+
+
+@pytest.mark.parametrize("spec", ["con4", "gap4"])
+def test_flash_forward_compiles(one_chip, spec):
+    run = flash_attention.make_kernel(1, H, HKV, SEQ, D, _cfg(spec),
+                                      return_residuals=True,
+                                      interpret=False)
+    _compile(run, one_chip, Q, KV, KV)
+
+
+def test_flash_backward_dq_compiles(one_chip):
+    run = flash_attention.make_bwd_dq_kernel(1, H, HKV, SEQ, D, _cfg("con4"),
+                                             interpret=False)
+    _compile(run, one_chip, Q, KV, KV, DO, RES, RES, RES)
+
+
+def test_flash_backward_dkv_compiles(one_chip):
+    run = flash_attention.make_bwd_dkv_kernel(1, H, HKV, SEQ, D,
+                                              _cfg("con4"), interpret=False)
+    _compile(run, one_chip, Q, KV, KV, DO, RES, RES, RES)
+
+
+def test_matmul_compiles(one_chip):
+    run = matmul.make_kernel(1024, 3072, 1024, _cfg("con4"), interpret=False)
+    _compile(run, one_chip, ((1024, 1024), jnp.float32),
+             ((1024, 3072), jnp.float32))

@@ -102,6 +102,7 @@ def test_sharded_train_step_matches_single_device():
     """Loss + grads identical (up to fp tolerance) on mesh (4,2) vs (1,1)."""
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.launch.steps import StepConfig, build_train_step
         from repro.launch.mesh import make_production_mesh
@@ -111,7 +112,7 @@ def test_sharded_train_step_matches_single_device():
         cfg = get_config("qwen3-0.6b").reduced()
         losses = {}
         for shape in [(1, 1), (4, 2)]:
-            mesh = jax.make_mesh(shape, ("data", "model"))
+            mesh = make_mesh(shape, ("data", "model"))
             sc = StepConfig(seq=32, batch=8, kind="train", n_micro=2,
                             remat="full")
             fn, _, in_sh, out_sh = build_train_step(cfg, mesh, sc)
@@ -137,10 +138,11 @@ def test_sharded_train_step_matches_single_device():
 def test_pipeline_parallel_matches_sequential():
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.distributed.pipeline import pipeline_apply
 
         S = 4
-        mesh = jax.make_mesh((S,), ("stage",))
+        mesh = make_mesh((S,), ("stage",))
         d = 16
         key = jax.random.PRNGKey(0)
         ws = jax.random.normal(key, (S, d, d)) * 0.3
@@ -164,10 +166,11 @@ def test_pipeline_parallel_gradients():
     PP, not just inference)."""
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.distributed.pipeline import pipeline_apply
 
         S, d = 4, 8
-        mesh = jax.make_mesh((S,), ("stage",))
+        mesh = make_mesh((S,), ("stage",))
         key = jax.random.PRNGKey(0)
         ws = jax.random.normal(key, (S, d, d)) * 0.3
         x = jax.random.normal(jax.random.fold_in(key, 1), (8, d))
@@ -196,9 +199,10 @@ def test_pipeline_parallel_gradients():
 def test_bucketed_psum_matches_pertensor():
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.distributed.collectives import bucketed_psum, pertensor_psum
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         key = jax.random.PRNGKey(0)
         grads = {f"p{i}": jax.random.normal(jax.random.fold_in(key, i),
                                             (33, 7)) for i in range(11)}
@@ -215,6 +219,7 @@ def test_moe_shardmap_matches_reference():
     capacity admits every token (no drops)."""
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import layers as L
         from repro.models.layers import NOSHARD
@@ -227,7 +232,7 @@ def test_moe_shardmap_matches_reference():
 
         y_ref, aux_ref = L.moe(p, x, cfg, capacity=32, shard=NOSHARD)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shard = make_shard_ctx(mesh)
         with mesh:
             y_sm, aux_sm = jax.jit(
@@ -262,9 +267,10 @@ def test_moe_shardmap_matches_reference():
 def test_int8_ef_psum_close_to_exact():
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.distributed.collectives import int8_ef_psum, pertensor_psum
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         key = jax.random.PRNGKey(0)
         grads = {"w": jax.random.normal(key, (64, 32)),
                  "b": jax.random.normal(jax.random.fold_in(key, 1), (17,))}
@@ -284,15 +290,16 @@ def test_elastic_restart_across_meshes():
     continues from the same state (elastic re-mesh)."""
     run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np, tempfile, os
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.launch.train import train
 
         cfg = get_config("qwen3-0.6b").reduced()
         d = tempfile.mkdtemp()
-        m1 = jax.make_mesh((2, 2), ("data", "model"))
+        m1 = make_mesh((2, 2), ("data", "model"))
         l1, _ = train(cfg, steps=3, batch=8, seq=32, ckpt_dir=d,
                       save_every=100, mesh=m1, log_every=100)
-        m2 = jax.make_mesh((8, 1), ("data", "model"))
+        m2 = make_mesh((8, 1), ("data", "model"))
         l2, _ = train(cfg, steps=5, batch=8, seq=32, ckpt_dir=d,
                       save_every=100, mesh=m2, log_every=100)
         assert len(l2) == 2, (len(l1), len(l2))   # resumed at step 3

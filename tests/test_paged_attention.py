@@ -36,16 +36,16 @@ def _fragmented():
     ``pos`` (page tails AND whole never-referenced pages) is poisoned with
     huge values so any unmasked read shows up as a parity failure."""
     rng = np.random.default_rng(11)
-    kp = rng.normal(size=(N_PAGES, PS, HKV, D)).astype(np.float32)
-    vp = rng.normal(size=(N_PAGES, PS, HKV, D)).astype(np.float32)
+    kp = rng.normal(size=(N_PAGES, HKV, PS, D)).astype(np.float32)
+    vp = rng.normal(size=(N_PAGES, HKV, PS, D)).astype(np.float32)
     perm = rng.permutation(np.arange(1, N_PAGES))[: B * NPP].reshape(B, NPP)
     pos = np.asarray([PS * 3 + 2, S - 1], np.int32)   # mid-page + full
     for bb in range(B):
         for lp in range(NPP):
             row0 = lp * PS
             dead = max(0, min(PS, pos[bb] + 1 - row0))
-            kp[perm[bb, lp], dead:] = 1e4
-            vp[perm[bb, lp], dead:] = 1e4
+            kp[perm[bb, lp], :, dead:] = 1e4
+            vp[perm[bb, lp], :, dead:] = 1e4
     unref = sorted(set(range(1, N_PAGES)) - set(perm.ravel()))
     kp[unref] = 1e4
     vp[unref] = 1e4
@@ -80,8 +80,8 @@ def test_paged_oracle_equals_contiguous_reference(data):
     """The gather oracle itself must agree with the plain contiguous path
     when the table is the identity layout."""
     q, kp, vp, bt, pos = data
-    k = kp[bt].reshape(B, S, HKV, D)
-    v = vp[bt].reshape(B, S, HKV, D)
+    k = jnp.swapaxes(kp[bt], 2, 3).reshape(B, S, HKV, D)
+    v = jnp.swapaxes(vp[bt], 2, 3).reshape(B, S, HKV, D)
     want = L.decode_attention(q, k, v, pos)
     got = ops.paged_decode_attention(q, kp, vp, bt, pos, backend="ref")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -181,3 +181,24 @@ def test_paged_auto_dispatch(scratch_default_cache, data):
     assert blob["entries"][spec.key]["cfg"] == best.label
     assert model_cost(spec, best) <= min(
         model_cost(spec, c) for c in enumerate_candidates(spec)) * (1 + 1e-9)
+
+
+@pytest.mark.parametrize("spec", SPECS)
+@pytest.mark.parametrize("window", [None, 11], ids=["causal", "window"])
+def test_verify_matches_contiguous_oracle(data, spec, window):
+    """The short-q verify kernel (T drafted rows per slot, row t at
+    pos0 + t) through the same fragmented, poisoned table must equal the
+    gather-to-contiguous verify oracle."""
+    q, kp, vp, bt, pos = data
+    t = 3
+    qt = jax.random.normal(KEY, (B, t, H, D), jnp.float32)
+    pos0 = pos - (t - 1)
+    cfg = CoarseningConfig.parse(spec) if spec != "none" \
+        else CoarseningConfig()
+    want = ops.flash_attention_verify(qt, kp, vp, bt, pos0, backend="ref",
+                                      window=window)
+    got = ops.flash_attention_verify(qt, kp, vp, bt, pos0, cfg,
+                                     backend="pallas", window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    assert np.abs(np.asarray(got)).max() < 100, "poisoned tail row leaked in"

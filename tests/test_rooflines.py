@@ -16,15 +16,6 @@ from repro.core.perfmodel import MeshInfo, train_step_terms, decode_step_terms
 from repro.configs import get_config
 
 
-def _cost_props(compiled):
-    """compiled.cost_analysis() returns a dict in jax>=0.4.27 but a
-    one-element list of dicts on older jaxlibs — normalise to the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return ca
-
-
 # ---------------------------------------------------------------------------
 # collective-bytes HLO parser
 # ---------------------------------------------------------------------------
@@ -52,10 +43,9 @@ def test_collective_bytes_parser():
 def test_collective_bytes_real_hlo():
     """Parse a real compiled psum HLO."""
     mesh = jax.make_mesh((1,), ("d",))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    f = shard_map(lambda x: lax.psum(x, "d"), mesh=mesh,
-                  in_specs=P(), out_specs=P(), check_rep=False)
+    f = jax.shard_map(lambda x: lax.psum(x, "d"), mesh=mesh,
+                      in_specs=P(), out_specs=P(), check_vma=False)
     hlo = jax.jit(f).lower(jnp.ones((64, 64))).compile().as_text()
     out = rooflines.collective_bytes(hlo)
     assert out["count"] >= 1
@@ -78,8 +68,8 @@ def test_cost_analysis_counts_while_once():
 
     w = jax.ShapeDtypeStruct((8, 128, 128), jnp.float32)
     x = jax.ShapeDtypeStruct((16, 128), jnp.float32)
-    fs = _cost_props(jax.jit(f_scan).lower(w, x).compile())["flops"]
-    fu = _cost_props(jax.jit(f_unroll).lower(w, x).compile())["flops"]
+    fs = jax.jit(f_scan).lower(w, x).compile().cost_analysis()["flops"]
+    fu = jax.jit(f_unroll).lower(w, x).compile().cost_analysis()["flops"]
     assert fu == pytest.approx(8 * fs, rel=0.01)
 
 
@@ -116,7 +106,7 @@ def test_perfmodel_matmul_flops_match_hlo():
     tok = jax.ShapeDtypeStruct((b, s), jnp.int32)
     p_abs = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                          params)
-    ca = _cost_props(jax.jit(fwd).lower(p_abs, tok).compile())
+    ca = jax.jit(fwd).lower(p_abs, tok).compile().cost_analysis()
     hlo_flops = ca["flops"]
 
     # analytic forward matmul+attention flops (train terms / bwd_mult, tp=1)
