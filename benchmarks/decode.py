@@ -178,7 +178,8 @@ def spec_serve_rows() -> None:
             break
         srv.step()
     emit("decode,spec,serve,contiguous", -1.0, -1.0,
-         decode_tok_s=round(srv.decoded_tokens / max(srv.decode_s, 1e-9), 1))
+         decode_tok_s=round(
+             srv.decoded_tokens / max(srv.decode_device_s, 1e-9), 1))
 
     def paged(make):
         eng = make()
@@ -191,13 +192,13 @@ def spec_serve_rows() -> None:
     kw = dict(slots=slots, num_pages=slots * (max_len // ps) + 1,
               page_size=ps, max_len=max_len, chunk=16)
     eng = paged(lambda: PagedEngine(cfg, params, decode_block=1, **kw))
-    base_tok_s = eng.decoded_tokens / max(eng.decode_s, 1e-9)
+    base_tok_s = eng.decoded_tokens / max(eng.decode_device_s, 1e-9)
     emit("decode,spec,serve,paged", -1.0, -1.0,
          decode_tok_s=round(base_tok_s, 1))
     for k in (2, 4, 8):
         eng = paged(lambda: SpecPagedEngine(
             cfg, params, spec_k=k, draft_cfg=cfg, draft_params=params, **kw))
-        tok_s = eng.decoded_tokens / max(eng.decode_s, 1e-9)
+        tok_s = eng.decoded_tokens / max(eng.decode_device_s, 1e-9)
         emit(f"decode,spec,serve,K{k}", -1.0, -1.0,
              decode_tok_s=round(tok_s, 1),
              acceptance=round(eng.acceptance_rate, 3),

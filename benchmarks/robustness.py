@@ -167,7 +167,7 @@ def swap_overhead_row(cfg, params) -> None:
     req = Request(rid=0, prompt=prompt, gen=12)
     eng.admit(0, req)
     eng.decode([0])
-    prefill_us = eng.prefill_s * 1e6          # what recompute re-pays
+    prefill_us = eng.prefill_device_s * 1e6    # what recompute re-pays
     t0 = time.perf_counter()
     susp = eng.suspend(0)
     eng.resume(1, susp)

@@ -78,7 +78,7 @@ def main():
             tag = f" ({r.preemptions} preemptions)" if r.preemptions else ""
             print(f"req {r.rid}: prompt[{len(r.prompt)}] -> "
                   f"{r.output[:8]}...{tag}")
-        rate = engine.decoded_tokens / max(engine.decode_s, 1e-9)
+        rate = engine.decoded_tokens / max(engine.decode_device_s, 1e-9)
         print(f"{len(done)} requests in {dt:.2f}s | pool "
               f"{engine.pool.capacity} pages x {engine.page_size} tok | "
               f"decode {rate:.1f} tok/s ({device_name()})")
@@ -98,9 +98,9 @@ def main():
         dt = time.perf_counter() - t0
         for i, out in enumerate(server.completed):
             print(f"req {i}: -> {out[:8]}...")
+        rate = server.decoded_tokens / max(server.decode_device_s, 1e-9)
         print(f"{len(server.completed)} requests in {dt:.2f}s | decode "
-              f"{server.decoded_tokens / max(server.decode_s, 1e-9):.1f} "
-              f"tok/s ({device_name()})")
+              f"{rate:.1f} tok/s ({device_name()})")
 
 
 if __name__ == "__main__":

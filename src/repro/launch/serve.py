@@ -157,7 +157,6 @@ class BatchedServer:
         # as overhead, not smeared into throughput
         self.prefill_steps = self.decode_steps = 0
         self.prefill_tokens = self.decoded_tokens = 0
-        self.prefill_s = self.decode_s = 0.0
         self.prefill_device_s = self.decode_device_s = 0.0
         self._prefill = jax.jit(
             lambda p, c, t, po, m: M.lm_prefill(p, {"tokens": t}, cfg,
@@ -206,7 +205,6 @@ class BatchedServer:
         if not free:
             return False
         s = free[0]
-        t0 = time.perf_counter()
         self.cache = _slot_reset(self.cache, jnp.asarray(s, jnp.int32))
         mask = jnp.zeros((self.slots,), bool).at[s].set(True)
         logits = None
@@ -221,7 +219,6 @@ class BatchedServer:
             self.prefill_steps += 1
         jax.block_until_ready(logits)
         self.prefill_device_s += time.perf_counter() - td
-        self.prefill_s += time.perf_counter() - t0
         self.prefill_tokens += len(prompt)
 
         if self.temperature > 0:
@@ -242,7 +239,6 @@ class BatchedServer:
     def step(self) -> None:
         if not self.active.any():
             return
-        t0 = time.perf_counter()
         act = np.flatnonzero(self.active)
         remaining = int(min(self.budget[s] - len(self.outputs[s])
                             for s in act))
@@ -265,7 +261,6 @@ class BatchedServer:
             self.decoded_tokens += take
             self.pos[s] += n
             self._maybe_finish(s)
-        self.decode_s += time.perf_counter() - t0
 
     def _maybe_finish(self, s: int) -> None:
         if len(self.outputs[s]) >= self.budget[s] \

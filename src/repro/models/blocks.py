@@ -209,6 +209,11 @@ def _paged_rows(block_table, pos, page_size):
     return block_table[bidx, pos // page_size], pos % page_size
 
 
+def _ffn_scope(cfg: ModelConfig) -> str:
+    """Named scope of a block's feed-forward half."""
+    return "moe" if cfg.n_experts else "ffn"
+
+
 def _pool_set(pool, page, row, val):
     """Write per-row values (pos.shape + (Hkv, ...)) into a head-major pool
     (P, Hkv, ps, ...) at physical (page, row)."""
@@ -230,38 +235,47 @@ def attn_block_decode_paged(p, x, cfg: ModelConfig, cache, *, kind: str, pos,
     to NULL before the scatter)."""
     window = cfg.window if kind == ATTN_LOCAL else None
     ps = cache["k"].shape[2]
-    h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    q, k, v = L.qkv_project(p["attn"], h, cfg, pos[:, None])
     quant = "k_scale" in cache
-    k_upd, v_upd = jax.lax.optimization_barrier((k[:, 0], v[:, 0]))
-    page, row = _paged_rows(block_table, pos, ps)
-    if write_mask is not None:
-        page = jnp.where(write_mask, page, 0)       # 0 == NULL_PAGE
     kscale = vscale = None
-    if quant:
-        from repro.quant.qtypes import quantize_kv
-        k_upd, ks_new = quantize_kv(k_upd.astype(jnp.float32))
-        v_upd, vs_new = quantize_kv(v_upd.astype(jnp.float32))
-        kscale = _pool_set(cache["k_scale"], page, row, ks_new)
-        vscale = _pool_set(cache["v_scale"], page, row, vs_new)
-    kc = _pool_set(cache["k"], page, row, k_upd)
-    vc = _pool_set(cache["v"], page, row, v_upd)
-    o = L.paged_decode_attention(q, kc, vc, block_table, pos, window=window,
-                                 backend=cfg.decode_backend,
-                                 cfg=cfg.decode_attn_cfg,
-                                 k_scale=kscale, v_scale=vscale)
-    o = o.reshape(x.shape[0], 1, -1) @ L.asdense(p["attn"]["wo"], x.dtype)
-    x = x + o
-    h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    if cfg.n_experts:
-        y, _ = L.moe(p["moe"], h, cfg, shard=shard,
-                     capacity=max(4, min(x.shape[0], 4 * cfg.top_k)))
-    else:
-        y = L.ffn(p["ffn"], h, backend=cfg.ffn_backend)
+    with jax.named_scope("attention"):
+        with jax.named_scope("qkv"):
+            h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+            q, k, v = L.qkv_project(p["attn"], h, cfg, pos[:, None])
+        with jax.named_scope("kv_write"):
+            k_upd, v_upd = jax.lax.optimization_barrier((k[:, 0], v[:, 0]))
+            page, row = _paged_rows(block_table, pos, ps)
+            if write_mask is not None:
+                page = jnp.where(write_mask, page, 0)       # 0 == NULL_PAGE
+            if quant:
+                from repro.quant.qtypes import quantize_kv
+                k_upd, ks_new = quantize_kv(k_upd.astype(jnp.float32))
+                v_upd, vs_new = quantize_kv(v_upd.astype(jnp.float32))
+                kscale = _pool_set(cache["k_scale"], page, row, ks_new)
+                vscale = _pool_set(cache["v_scale"], page, row, vs_new)
+            kc = _pool_set(cache["k"], page, row, k_upd)
+            vc = _pool_set(cache["v"], page, row, v_upd)
+        with jax.named_scope("core"):
+            o = L.paged_decode_attention(q, kc, vc, block_table, pos,
+                                         window=window,
+                                         backend=cfg.decode_backend,
+                                         cfg=cfg.decode_attn_cfg,
+                                         k_scale=kscale, v_scale=vscale)
+        with jax.named_scope("out"):
+            o = o.reshape(x.shape[0], 1, -1) \
+                @ L.asdense(p["attn"]["wo"], x.dtype)
+            x = x + o
+    with jax.named_scope(_ffn_scope(cfg)):
+        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+        if cfg.n_experts:
+            y, _ = L.moe(p["moe"], h, cfg, shard=shard,
+                         capacity=max(4, min(x.shape[0], 4 * cfg.top_k)))
+        else:
+            y = L.ffn(p["ffn"], h, backend=cfg.ffn_backend)
+        x = x + y
     newc = {"k": kc, "v": vc}
     if quant:
         newc.update(k_scale=kscale, v_scale=vscale)
-    return x + y, newc
+    return x, newc
 
 
 def attn_block_prefill_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
@@ -275,41 +289,51 @@ def attn_block_prefill_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
     ps = cache["k"].shape[2]
     window = cfg.window if kind == ATTN_LOCAL else None
     pos = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None]     # (B,T)
-    h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    q, k, v = L.qkv_project(p["attn"], h, cfg, pos)
-    page, row = _paged_rows(block_table, pos, ps)
     quant = "k_scale" in cache
     newc = {}
-    if quant:
-        from repro.quant.qtypes import quantize_kv
-        kq, ks_new = quantize_kv(k.astype(jnp.float32))
-        vq, vs_new = quantize_kv(v.astype(jnp.float32))
-        k_upd, v_upd = jax.lax.optimization_barrier((kq, vq))
-        newc["k_scale"] = _pool_set(cache["k_scale"], page, row, ks_new)
-        newc["v_scale"] = _pool_set(cache["v_scale"], page, row, vs_new)
-    else:
-        k_upd, v_upd = jax.lax.optimization_barrier(
-            (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)))
-    kc = _pool_set(cache["k"], page, row, k_upd)
-    vc = _pool_set(cache["v"], page, row, v_upd)
-    from repro.kernels.ref import paged_view
-    ka, va = paged_view(kc, block_table), paged_view(vc, block_table)
-    if quant:
-        from repro.quant.qtypes import dequantize_kv
-        ka = dequantize_kv(ka, paged_view(newc["k_scale"], block_table)
-                           ).astype(x.dtype)
-        va = dequantize_kv(va, paged_view(newc["v_scale"], block_table)
-                           ).astype(x.dtype)
-    o = L.flash_attention(q, ka, va, causal=True, window=window, q_pos=pos,
-                          **_attn_kw(cfg))
-    o = o.reshape(b, t, -1) @ L.asdense(p["attn"]["wo"], x.dtype)
-    x = x + o
-    h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    if cfg.n_experts:
-        y, _ = L.moe(p["moe"], h, cfg, capacity=b * t)
-    else:
-        y = L.ffn(p["ffn"], h, backend=cfg.ffn_backend)
-    return x + y, {"k": kc, "v": vc, **newc}
+    with jax.named_scope("attention"):
+        with jax.named_scope("qkv"):
+            h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+            q, k, v = L.qkv_project(p["attn"], h, cfg, pos)
+        with jax.named_scope("kv_write"):
+            page, row = _paged_rows(block_table, pos, ps)
+            if quant:
+                from repro.quant.qtypes import quantize_kv
+                kq, ks_new = quantize_kv(k.astype(jnp.float32))
+                vq, vs_new = quantize_kv(v.astype(jnp.float32))
+                k_upd, v_upd = jax.lax.optimization_barrier((kq, vq))
+                newc["k_scale"] = _pool_set(cache["k_scale"], page, row,
+                                            ks_new)
+                newc["v_scale"] = _pool_set(cache["v_scale"], page, row,
+                                            vs_new)
+            else:
+                k_upd, v_upd = jax.lax.optimization_barrier(
+                    (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)))
+            kc = _pool_set(cache["k"], page, row, k_upd)
+            vc = _pool_set(cache["v"], page, row, v_upd)
+        with jax.named_scope("core"):
+            # the slot's whole logical view gathered out of the pool
+            from repro.kernels.ref import paged_view
+            ka, va = paged_view(kc, block_table), paged_view(vc, block_table)
+            if quant:
+                from repro.quant.qtypes import dequantize_kv
+                ka = dequantize_kv(ka, paged_view(newc["k_scale"],
+                                                  block_table)).astype(x.dtype)
+                va = dequantize_kv(va, paged_view(newc["v_scale"],
+                                                  block_table)).astype(x.dtype)
+            o = L.flash_attention(q, ka, va, causal=True, window=window,
+                                  q_pos=pos, **_attn_kw(cfg))
+        with jax.named_scope("out"):
+            o = o.reshape(b, t, -1) @ L.asdense(p["attn"]["wo"], x.dtype)
+            x = x + o
+    with jax.named_scope(_ffn_scope(cfg)):
+        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+        if cfg.n_experts:
+            y, _ = L.moe(p["moe"], h, cfg, capacity=b * t)
+        else:
+            y = L.ffn(p["ffn"], h, backend=cfg.ffn_backend)
+        x = x + y
+    return x, {"k": kc, "v": vc, **newc}
 
 
 def attn_block_verify_paged(p, x, cfg: ModelConfig, cache, *, kind: str,

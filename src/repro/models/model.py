@@ -405,6 +405,23 @@ def lm_init_cache_paged(cfg: ModelConfig, b: int, num_pages: int,
     }
 
 
+def _cache_layer(caches, i):
+    """Layer ``i``'s slice of each stacked cache (the scan's per-layer
+    read of the carried stack)."""
+    with jax.named_scope("cache_io"):
+        return [jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), c)
+            for c in caches]
+
+
+def _cache_layer_set(caches, new, i):
+    """The stacked caches with layer ``i`` replaced by ``new``."""
+    with jax.named_scope("cache_io"):
+        return [jax.tree.map(
+            lambda a, u: lax.dynamic_update_index_in_dim(a, u, i, 0), c, nc)
+            for c, nc in zip(caches, new)]
+
+
 def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
                    shard: ShardCtx = NOSHARD, block_table=None,
                    write_mask=None):
@@ -416,7 +433,8 @@ def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
     ``write_mask`` (B,) bool (paged only) suppresses a slot's cache write
     — the speculative draft scan's padding guard."""
     period, n_periods, tail = _period(cfg)
-    x = _embed(params, tokens, cfg, {"tokens": tokens})
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, cfg, {"tokens": tokens})
 
     kinds = period
 
@@ -425,9 +443,7 @@ def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
     # XLA materialize a second full-cache buffer (observed as an f32 copy).
     def period_body(carry, pblk):
         x, caches, i = carry
-        cblk = [jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), c)
-            for c in caches]
+        cblk = _cache_layer(caches, i)
         newc = []
         for j, kind in enumerate(kinds):
             if cfg.is_encdec:
@@ -439,10 +455,7 @@ def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
                                       block_table=block_table,
                                       write_mask=write_mask)
             newc.append(nc)
-        caches = [jax.tree.map(
-            lambda a, u: lax.dynamic_update_index_in_dim(a, u, i, 0), c, nc)
-            for c, nc in zip(caches, newc)]
-        return (x, caches, i + 1), None
+        return (x, _cache_layer_set(caches, newc, i), i + 1), None
 
     (x, new_blocks, _), _ = lax.scan(
         period_body, (x, list(cache["blocks"]), jnp.asarray(0, jnp.int32)),
@@ -453,9 +466,11 @@ def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
                               block_table=block_table, write_mask=write_mask)
         new_tail.append(nc)
 
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = (x[:, 0] @ _head(params, cfg).astype(x.dtype)).astype(jnp.float32)
-    logits = logits[:, : cfg.vocab]               # drop pad-vocab ids
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = (x[:, 0] @ _head(params, cfg).astype(x.dtype)
+                  ).astype(jnp.float32)
+        logits = logits[:, : cfg.vocab]               # drop pad-vocab ids
     return logits, {"blocks": list(new_blocks), "tail": new_tail}
 
 
@@ -483,19 +498,14 @@ def lm_verify_step(params, cache, tokens, pos0, cfg: ModelConfig, *,
 
     def period_body(carry, pblk):
         x, caches, i = carry
-        cblk = [jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), c)
-            for c in caches]
+        cblk = _cache_layer(caches, i)
         newc = []
         for j, kind in enumerate(kinds):
             x, nc = _block_verify(kind, pblk[j], x, cfg, cblk[j], pos0=pos0,
                                   block_table=block_table,
                                   valid_len=valid_len, shard=shard)
             newc.append(nc)
-        caches = [jax.tree.map(
-            lambda a, u: lax.dynamic_update_index_in_dim(a, u, i, 0), c, nc)
-            for c, nc in zip(caches, newc)]
-        return (x, caches, i + 1), None
+        return (x, _cache_layer_set(caches, newc, i), i + 1), None
 
     (x, new_blocks, _), _ = lax.scan(
         period_body, (x, list(cache["blocks"]), jnp.asarray(0, jnp.int32)),
@@ -621,14 +631,13 @@ def lm_prefill(params, batch, cfg: ModelConfig, s_max: int | None = None, *,
     if cfg.is_encdec and batch.get("src_frames") is not None:
         cache = _prefill_enc_cache(params, batch, cfg, cache)
 
-    x = _embed(params, tokens, cfg, batch)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, cfg, batch)
     kinds = period
 
     def period_body(carry, pblk):
         x, caches, i = carry
-        cblk = [jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), c)
-            for c in caches]
+        cblk = _cache_layer(caches, i)
         newc = []
         for j, kind in enumerate(kinds):
             if cfg.is_encdec:
@@ -638,10 +647,7 @@ def lm_prefill(params, batch, cfg: ModelConfig, s_max: int | None = None, *,
                 x, nc = _block_prefill(kind, pblk[j], x, cfg, cblk[j],
                                        pos0=pos0, block_table=block_table)
             newc.append(nc)
-        caches = [jax.tree.map(
-            lambda a, u: lax.dynamic_update_index_in_dim(a, u, i, 0), c, nc)
-            for c, nc in zip(caches, newc)]
-        return (x, caches, i + 1), None
+        return (x, _cache_layer_set(caches, newc, i), i + 1), None
 
     (x, new_blocks, _), _ = lax.scan(
         period_body, (x, list(cache["blocks"]), jnp.asarray(0, jnp.int32)),
@@ -662,15 +668,19 @@ def lm_prefill(params, batch, cfg: ModelConfig, s_max: int | None = None, *,
                 return n
             return _select_slots(mask, n, o, batch_axis=batch_axis)
 
-        new_cache = {
-            "blocks": [committed(n, o, kind, 1)
-                       for n, o, kind in zip(new_cache["blocks"],
-                                             old_cache["blocks"], period)],
-            "tail": [committed(n, o, kind, 0)
-                     for n, o, kind in zip(new_cache["tail"],
-                                           old_cache["tail"], tail)],
-        }
+        with jax.named_scope("cache_io"):
+            new_cache = {
+                "blocks": [committed(n, o, kind, 1)
+                           for n, o, kind in zip(new_cache["blocks"],
+                                                 old_cache["blocks"], period)],
+                "tail": [committed(n, o, kind, 0)
+                         for n, o, kind in zip(new_cache["tail"],
+                                               old_cache["tail"], tail)],
+            }
 
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = (x[:, -1] @ _head(params, cfg).astype(x.dtype)).astype(jnp.float32)
-    return logits[:, : cfg.vocab], new_cache
+    with jax.named_scope("head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = (x[:, -1] @ _head(params, cfg).astype(x.dtype)
+                  ).astype(jnp.float32)
+        logits = logits[:, : cfg.vocab]
+    return logits, new_cache

@@ -23,7 +23,11 @@ Cost model:
   code needs no ``if tracing:`` guards.
 * **enabled**: one tuple append into a ``deque(maxlen=capacity)`` per
   event — the ring drops the oldest events once full (``dropped`` counts
-  them) so a long-running server holds bounded memory.
+  them) so a long-running server holds bounded memory.  Each span also
+  opens a ``jax.profiler.TraceAnnotation`` named ``repro.<span name>``
+  (closed by ``end``), so under ``jax.profiler`` the spans sit on the
+  profiler's clock beside the device's operations; instant events stay in
+  the recorder only.
 
 Track layout (Chrome ``tid``): engine slots trace as tid 0..slots-1,
 batch-level engine work (decode blocks, verify passes) on ``ENGINE_TRACK``,
@@ -36,6 +40,11 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
+
+# prefix of the profiler annotation each span opens
+ANNOTATION_PREFIX = "repro."
 
 # Chrome trace-event phases this recorder emits: X complete, i instant,
 # M metadata.  validate_chrome accepts exactly these.
@@ -143,8 +152,10 @@ class TraceRecorder:
         spans always nest and never overlap within a track."""
         if not self.enabled:
             return
+        ann = TraceAnnotation(ANNOTATION_PREFIX + name)
+        ann.__enter__()
         self._open.setdefault(int(tid), []).append(
-            (name, cat, self._now_us(), self.quantum, args))
+            (name, cat, self._now_us(), self.quantum, args, ann))
 
     def end(self, tid: int = 0) -> None:
         """Close the innermost open span on ``tid`` as a complete event."""
@@ -153,13 +164,14 @@ class TraceRecorder:
         stack = self._open.get(int(tid))
         if not stack:
             raise RuntimeError(f"end() with no open span on tid {tid}")
-        name, cat, ts, q, args = stack.pop()
+        name, cat, ts, q, args, ann = stack.pop()
         # the span keeps its OPENING quantum: that is the step it belongs to
         seq = self._seq = self._seq + 1
         if len(self._buf) == self.capacity:
             self.dropped += 1
         self._buf.append((seq, name, "X", cat, int(tid), ts,
                           max(0.0, self._now_us() - ts), q, args))
+        ann.__exit__(None, None, None)
 
     def span(self, name: str, cat: str = "engine", tid: int = 0,
              args: dict | None = None):

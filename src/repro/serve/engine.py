@@ -159,14 +159,16 @@ class PagedEngine:
         # host bookkeeping) — what the tok/s lines should divide by
         self._c_prefill_dev = o.counter("engine_prefill_device_seconds_total")
         self._c_decode_dev = o.counter("engine_decode_device_seconds_total")
-        self.prefill_s = self.decode_s = 0.0   # legacy whole-call timers
         self.fault_hook = None          # repro.serve.faults sets this
         self._attn_kinds = self._kind_flags(cfg)
         self._swap_page_bytes, self._swap_fixed_bytes = self._swap_layout()
-        self._prefill = jax.jit(
-            lambda p, c, t, po, m, bt: M.lm_prefill(
-                p, {"tokens": t}, cfg, cache=c, pos0=po, mask=m,
-                block_table=bt))
+
+        # named, so the compiled module reads jit_engine_prefill in a trace
+        def engine_prefill(p, c, t, po, m, bt):
+            return M.lm_prefill(p, {"tokens": t}, cfg, cache=c, pos0=po,
+                                mask=m, block_table=bt)
+
+        self._prefill = jax.jit(engine_prefill)
         self._decode_fns: dict[int, Any] = {}
 
     # -- static layout helpers ----------------------------------------------
@@ -390,16 +392,16 @@ class PagedEngine:
         only[slot] = True
         bt_dev = self._device_table(only)    # other slots' writes -> null
         logits = None
-        t0 = time.perf_counter()
+        tr = self.trace
         for i in range(0, len(tokens), self.chunk):
             piece = tokens[i:i + self.chunk]
             buf = np.zeros((self.slots, len(piece)), np.int32)
             buf[slot] = piece
             pos0 = jnp.asarray(self.written, jnp.int32).at[slot].set(
                 pos_start + i)
-            with self.trace.span("prefill.chunk", "engine", slot,
-                                 {"rid": rid, "pos": pos_start + i,
-                                  "n": len(piece)}):
+            with tr.span("prefill.chunk", "engine", slot,
+                         {"rid": rid, "pos": pos_start + i, "n": len(piece)}
+                         if tr else None):
                 td = time.perf_counter()
                 logits, self.cache = self._prefill(
                     self.params, self.cache, jnp.asarray(buf), pos0, mask,
@@ -407,7 +409,6 @@ class PagedEngine:
                 jax.block_until_ready(logits)
                 self._c_prefill_dev.inc(time.perf_counter() - td)
             self._c_prefill_steps.inc()
-        self.prefill_s += time.perf_counter() - t0
         self._c_prefill_tokens.inc(len(tokens))
         return logits[slot]
 
@@ -416,7 +417,18 @@ class PagedEngine:
     def admit(self, slot: int, req) -> int:
         """Allocate pages, ingest the prompt, return the first greedy token.
         Raises ValueError for prompts that can never fit, PoolExhausted when
-        the pool can't serve the prompt right now (no partial effects)."""
+        the pool can't serve the prompt right now (no partial effects).
+
+        Traced as ``engine.admit`` on the slot's track: its ``prefill.chunk``
+        and ``admit.first_token`` children are device calls and their host
+        reads, so its self time is the host work and eager ops between
+        them (allocation, slot reset, block-table upload)."""
+        tr = self.trace
+        with tr.span("engine.admit", "engine", slot,
+                     {"rid": getattr(req, "rid", None)} if tr else None):
+            return self._admit(slot, req)
+
+    def _admit(self, slot: int, req) -> int:
         if self.active[slot]:
             raise RuntimeError(f"slot {slot} is already running")
         prompt, gen = list(req.prompt), int(req.gen)
@@ -445,7 +457,8 @@ class PagedEngine:
             self._slot_load(slot, pre.state)
         logits = self._run_prefill(slot, prompt[start:], start,
                                    rid=getattr(req, "rid", None))
-        first = int(jnp.argmax(logits))
+        with self.trace.span("admit.first_token", "engine", slot):
+            first = int(jnp.argmax(logits))
         self.active[slot] = True
         self.written[slot] = len(prompt)
         self.last[slot] = first
@@ -458,12 +471,14 @@ class PagedEngine:
             return fn
         cfg = self.cfg
 
-        def run(params, cache, tok, pos, bt):
+        # named, so the compiled module reads jit_engine_decode in a trace
+        def engine_decode(params, cache, tok, pos, bt):
             def body(carry, _):
                 tok, pos, cache = carry
                 logits, cache = M.lm_decode_step(params, cache, tok, pos,
                                                  cfg, block_table=bt)
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                with jax.named_scope("head"):
+                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
                 return (nxt[:, None], pos + 1, cache), (nxt, logits)
 
             (_, _, cache), (toks, lgs) = jax.lax.scan(
@@ -472,7 +487,7 @@ class PagedEngine:
             # visible logits feed the NaN guard below
             return toks.T, jnp.moveaxis(lgs, 0, 1), cache
 
-        fn = self._decode_fns[n] = jax.jit(run)
+        fn = self._decode_fns[n] = jax.jit(engine_decode)
         return fn
 
     def decode(self, slots) -> dict[int, list[int]]:
@@ -488,22 +503,58 @@ class PagedEngine:
         graph, same inputs; stale rows past ``written`` are pos-masked), so
         a rescued block's tokens are exactly the fault-free ones.  Retries
         are bounded; exhaustion raises DecodeFault with the per-slot
-        cursors unadvanced (the scheduler retries the quantum)."""
+        cursors unadvanced (the scheduler retries the quantum).
+
+        Traced as ``decode.block`` on the engine track, holding one
+        ``decode.launch`` (exactly the device-seconds interval),
+        ``decode.fetch`` (host copies of tokens and logits) and
+        ``decode.guard`` (the NaN scan) per attempt; its self time is page
+        growth and token bookkeeping."""
         slots = [s for s in slots if self.active[s]]
         if not slots:
             return {}
         n = max(1, min(self.decode_block,
                        *(int(self.remaining[s]) for s in slots)))
-        for s in slots:
-            need = pages_needed(int(self.written[s]) + n, self.page_size) \
-                - self.bt.num_pages(s)
-            if need > 0:
-                self.bt.append(s, self.pool.alloc(need))
-        tokens = np.zeros((self.slots, 1), np.int32)
-        tokens[slots, 0] = self.last[slots]
-        t0 = time.perf_counter()
+        tr = self.trace
+        with tr.span("decode.block", "engine", ENGINE_TRACK,
+                     {"slots": len(slots), "n": n} if tr else None):
+            for s in slots:
+                need = pages_needed(int(self.written[s]) + n,
+                                    self.page_size) - self.bt.num_pages(s)
+                if need > 0:
+                    self.bt.append(s, self.pool.alloc(need))
+            tokens = np.zeros((self.slots, 1), np.int32)
+            tokens[slots, 0] = self.last[slots]
+            retries = 0
+            while True:
+                toks, lg = self._launch_decode(n, tokens)
+                with tr.span("decode.guard", "engine", ENGINE_TRACK):
+                    poisoned = np.isnan(lg[slots]).any()
+                if not poisoned:
+                    break
+                retries += 1
+                if retries > 4:
+                    raise DecodeFault(
+                        f"non-finite logits persisted through {retries - 1} "
+                        f"rescue re-runs")
+                self._c_nan_rescues.inc()
+                tr.event("nan.rescue", "engine", ENGINE_TRACK,
+                         {"retry": retries})
+            self._c_decode_steps.inc(n)
+            self._c_decode_tokens.inc(n * len(slots))
+            out = {}
+            for s in slots:
+                out[s] = [int(v) for v in toks[s]]
+                self.last[s] = toks[s, -1]
+                self.written[s] += n
+                self.remaining[s] -= n
+        return out
 
-        def launch():
+    def _launch_decode(self, n: int, tokens):
+        """One decode block on the device for every slot; returns the
+        host copies of its (slots, n) tokens and (slots, n, V) logits."""
+        tr = self.trace
+        with tr.span("decode.launch", "engine", ENGINE_TRACK):
             td = time.perf_counter()
             toks, lgs, self.cache = self._decode_fn(n)(
                 self.params, self.cache, jnp.asarray(tokens),
@@ -511,36 +562,12 @@ class PagedEngine:
                 self._device_table(self.active))
             jax.block_until_ready(lgs)
             self._c_decode_dev.inc(time.perf_counter() - td)
+        with tr.span("decode.fetch", "engine", ENGINE_TRACK):
             lg = np.asarray(lgs)
             if self.fault_hook is not None:
                 lg = self.fault_hook.corrupt_logits(lg, site="decode")
-            return np.asarray(toks), lg
-
-        with self.trace.span("decode.block", "engine", ENGINE_TRACK,
-                             {"slots": len(slots), "n": n}):
-            toks, lg = launch()
-            retries = 0
-            while np.isnan(lg[slots]).any():
-                retries += 1
-                if retries > 4:
-                    self.decode_s += time.perf_counter() - t0
-                    raise DecodeFault(
-                        f"non-finite logits persisted through {retries - 1} "
-                        f"rescue re-runs")
-                self._c_nan_rescues.inc()
-                self.trace.event("nan.rescue", "engine", ENGINE_TRACK,
-                                 {"retry": retries})
-                toks, lg = launch()
-        self.decode_s += time.perf_counter() - t0
-        self._c_decode_steps.inc(n)
-        self._c_decode_tokens.inc(n * len(slots))
-        out = {}
-        for s in slots:
-            out[s] = [int(v) for v in toks[s]]
-            self.last[s] = toks[s, -1]
-            self.written[s] += n
-            self.remaining[s] -= n
-        return out
+            toks = np.asarray(toks)
+        return toks, lg
 
     # -- admission accounting ------------------------------------------------
 

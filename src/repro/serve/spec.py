@@ -244,8 +244,8 @@ class SpecPagedEngine(PagedEngine):
                 self.draft_params, self.draft_cache, jnp.asarray(buf), pos0,
                 mask, bt_dev)
 
-    def admit(self, slot: int, req) -> int:
-        first = super().admit(slot, req)
+    def _admit(self, slot: int, req) -> int:
+        first = super()._admit(slot, req)
         # no draft-side allocation: the target's pages cover the draft, so
         # this cannot raise PoolExhausted after super() succeeded
         self._run_draft_prefill(slot, list(req.prompt))
@@ -288,20 +288,28 @@ class SpecPagedEngine(PagedEngine):
         slots = [s for s in slots if self.active[s]]
         if not slots:
             return {}
-        ps = self.page_size
         keff = np.zeros((self.slots,), np.int32)
         for s in slots:
             # never draft past the request's budget: a step emits at most
             # keff+1 tokens and remaining >= 1 here
             keff[s] = min(self.spec_k, int(self.remaining[s]) - 1)
         kpad = int(keff[slots].max())
+        tr = self.trace
+        with tr.span("verify.pass", "engine", ENGINE_TRACK,
+                     {"slots": len(slots), "k": kpad} if tr else None):
+            return self._spec_step(slots, keff, kpad)
+
+    def _spec_step(self, slots, keff, kpad) -> dict[int, list[int]]:
+        """The body of ``decode``, traced like the base engine's block:
+        ``decode.launch`` (draft scan and verify, exactly the device-seconds
+        interval), ``decode.fetch`` (the logits' host copy) and
+        ``decode.guard`` (tie and NaN guards)."""
+        ps, tr = self.page_size, self.trace
         for s in slots:
             need = pages_needed(int(self.written[s]) + int(keff[s]) + 1, ps) \
                 - self.bt.num_pages(s)
             if need > 0:
                 self.bt.append(s, self.pool.alloc(need))
-
-        t0 = time.perf_counter()
         bt_dev = self._device_table(self.active)
         pos0 = jnp.asarray(self.written, jnp.int32)
         keff_dev = jnp.asarray(keff, jnp.int32)
@@ -313,8 +321,7 @@ class SpecPagedEngine(PagedEngine):
         # writing draft rows written..written+keff — the draft cache ends
         # one row AHEAD of the accepted prefix in the all-accept case and
         # exactly at it after a rollback, both equal to new_written
-        with self.trace.span("verify.pass", "engine", ENGINE_TRACK,
-                             {"slots": len(slots), "k": kpad}):
+        with tr.span("decode.launch", "engine", ENGINE_TRACK):
             td = time.perf_counter()
             drafts, self.draft_cache = self._draft_fn(kpad + 1)(
                 self.draft_params, self.draft_cache, last_dev, pos0,
@@ -327,27 +334,30 @@ class SpecPagedEngine(PagedEngine):
                 self.params, self.cache, vtok, pos0, keff_dev + 1, bt_dev)
             jax.block_until_ready(logits)
             self._c_decode_dev.inc(time.perf_counter() - td)
-        lg = np.asarray(logits, np.float32)              # (slots, kpad+1, V)
-        if self.fault_hook is not None:
-            lg = self.fault_hook.corrupt_logits(lg, site="verify")
-        greedy = lg.argmax(-1)
-        top2 = np.partition(lg, -2, axis=-1)[..., -2:]
-        # tie guard threshold: margin relative to the row's logit spread
-        # (inter-graph divergence scales with activation magnitude)
-        clear = (top2[..., 1] - top2[..., 0]) >= self.tie_tau * lg.std(-1)
-        # NaN guard: a poisoned (non-finite) verify row compares False into
-        # ``clear`` already, but make it explicit — the row is voided, so
-        # emission stops before it and the decode-graph rescue below takes
-        # over when nothing else would emit.  That is the whole fault story:
-        # no token derived from a poisoned row can ever be emitted.
-        finite = np.isfinite(lg).all(-1)
-        voided = int((~finite[np.asarray(slots)]).sum())
+        with tr.span("decode.fetch", "engine", ENGINE_TRACK):
+            lg = np.asarray(logits, np.float32)          # (slots, kpad+1, V)
+            if self.fault_hook is not None:
+                lg = self.fault_hook.corrupt_logits(lg, site="verify")
+            drafts = np.asarray(drafts)
+        with tr.span("decode.guard", "engine", ENGINE_TRACK):
+            greedy = lg.argmax(-1)
+            top2 = np.partition(lg, -2, axis=-1)[..., -2:]
+            # tie guard threshold: margin relative to the row's logit
+            # spread (inter-graph divergence scales with activation
+            # magnitude)
+            clear = (top2[..., 1] - top2[..., 0]) >= self.tie_tau * lg.std(-1)
+            # NaN guard: a poisoned (non-finite) verify row compares False
+            # into ``clear`` already, but make it explicit — the row is
+            # voided, so emission stops before it and the decode-graph
+            # rescue below takes over when nothing else would emit.  That
+            # is the whole fault story: no token derived from a poisoned
+            # row can ever be emitted.
+            finite = np.isfinite(lg).all(-1)
+            voided = int((~finite[np.asarray(slots)]).sum())
+            clear &= finite
         if voided:
             self._c_nan_rows.inc(voided)
-            self.trace.event("nan.voided", "engine", ENGINE_TRACK,
-                             {"rows": voided})
-        clear &= finite
-        drafts = np.asarray(drafts)
+            tr.event("nan.voided", "engine", ENGINE_TRACK, {"rows": voided})
         self._c_decode_steps.inc()
         self._c_spec_steps.inc()
 
@@ -397,16 +407,18 @@ class SpecPagedEngine(PagedEngine):
             self._c_rescues.inc()
             tokens = np.zeros((self.slots, 1), np.int32)
             tokens[slots, 0] = self.last[slots]
-            with self.trace.span("decode.rescue", "engine", ENGINE_TRACK,
-                                 {"slots": len(rescue)}):
-                td = time.perf_counter()
-                toks, _, self.cache = self._decode_fn(1)(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(self.written, jnp.int32),
-                    self._device_table(self.active))
-                jax.block_until_ready(toks)
-                self._c_decode_dev.inc(time.perf_counter() - td)
-            toks = np.asarray(toks)
+            with tr.span("decode.rescue", "engine", ENGINE_TRACK,
+                         {"slots": len(rescue)} if tr else None):
+                with tr.span("decode.launch", "engine", ENGINE_TRACK):
+                    td = time.perf_counter()
+                    toks, _, self.cache = self._decode_fn(1)(
+                        self.params, self.cache, jnp.asarray(tokens),
+                        jnp.asarray(self.written, jnp.int32),
+                        self._device_table(self.active))
+                    jax.block_until_ready(toks)
+                    self._c_decode_dev.inc(time.perf_counter() - td)
+                with tr.span("decode.fetch", "engine", ENGINE_TRACK):
+                    toks = np.asarray(toks)
             for s in rescue:
                 tok = int(toks[s, 0])
                 out[s] = [tok]
@@ -414,5 +426,4 @@ class SpecPagedEngine(PagedEngine):
                 self.last[s] = tok
                 self.remaining[s] -= 1
                 self._c_decode_tokens.inc()
-        self.decode_s += time.perf_counter() - t0
         return out

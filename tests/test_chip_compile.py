@@ -103,3 +103,84 @@ def test_matmul_compiles(one_chip):
     run = matmul.make_kernel(1024, 3072, 1024, _cfg("con4"), interpret=False)
     _compile(run, one_chip, ((1024, 1024), jnp.float32),
              ((1024, 3072), jnp.float32))
+
+
+# the model's named scopes, and the leaf ops a compiled decode program may
+# hold outside them: copies, which the compiler makes itself (whole-stack
+# copies, loop-carry and layout copies); ops it makes with no op_name (the
+# weights' bf16 casts hoisted out of the loops, buffer allocations); and
+# the scan machinery engine_decode traces itself (loop counters and the
+# per-step outputs stacked into the block's)
+MODEL_SCOPES = {"embed", "attention", "ffn", "moe", "head", "cache_io"}
+COPIES = {"copy", "copy-start", "copy-done"}
+COMPILER_MADE = {"convert", "custom-call"}
+SCAN_MACHINERY = {"add", "lt", "dynamic_update_slice", "broadcast_in_dim"}
+NO_OPS = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+          "while", "conditional"}
+
+
+def _leaf_ops(hlo: str):
+    """(opcode, op_name) of every instruction of the entry computation and
+    the while bodies and conditions: what a device trace shows."""
+    import re
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(ENTRY )?%(\S+) ", line)
+        if m and line.rstrip().endswith("{"):
+            cur = m.group(2)
+            comps[cur] = (bool(m.group(1)), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur and " = " in line:
+            comps[cur][1].append(line)
+    control = {n for n, (entry, _) in comps.items() if entry}
+    for _, body in comps.values():
+        for line in body:
+            control.update(m.group(1) for m in re.finditer(
+                r"(?:body|condition)=%([\w.\-]+)", line))
+    for n in control:
+        for line in comps[n][1]:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = \S+ ([a-z][\w\-]*)\(", line)
+            if m and m.group(1) not in NO_OPS:
+                on = re.search(r'op_name="([^"]*)"', line)
+                yield m.group(1), on.group(1) if on else ""
+
+
+def test_decode_program_ops_carry_model_scopes(one_chip):
+    """Every leaf op of a tiny engine_decode program compiled for the chip
+    carries a model scope in its op_name, or is one of the listed unscoped
+    kinds; the scopes the device-time reduction reads all appear."""
+    import numpy as np
+    from repro.configs import get_config
+    from repro.models import model as M
+    from repro.serve import PagedEngine
+
+    cfg = get_config("qwen3-0.6b").reduced()
+    eng = PagedEngine(cfg, M.lm_init(jax.random.PRNGKey(0), cfg), slots=2,
+                      num_pages=9, page_size=8, max_len=32, chunk=8,
+                      decode_block=4)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (eng.params, eng.cache, jnp.zeros((2, 1), jnp.int32),
+         jnp.zeros((2,), jnp.int32), eng._device_table(np.ones(2, bool))))
+    hlo = eng._decode_fn(4).lower(*args).compile().as_text()
+    seen, stray = set(), []
+    for op, name in _leaf_ops(hlo):
+        parts = name.split("/")
+        scopes = [p for p in parts if p in MODEL_SCOPES]
+        if scopes:
+            seen.add("/".join(p for p in parts if p in MODEL_SCOPES
+                              or p in {"qkv", "kv_write", "core", "out"}))
+        elif op in COPIES:
+            continue
+        elif not name:
+            if op not in COMPILER_MADE:
+                stray.append((op, name))
+        elif not (parts[0] == "jit(engine_decode)"
+                  and set(parts[1:-1]) <= {"while", "body", "cond",
+                                           "closed_call"}
+                  and parts[-1] in SCAN_MACHINERY):
+            stray.append((op, name))
+    assert not stray, stray
+    assert {"embed", "attention/qkv", "attention/kv_write", "attention/core",
+            "cache_io", "ffn", "head"} <= seen, seen

@@ -25,9 +25,9 @@ import tracemalloc
 import numpy as np
 import pytest
 
-from repro.obs import (ENGINE_TRACK, QUANTA_BUCKETS, REQ_TRACK_BASE,
-                       SCHED_TRACK, TERMINAL_STATES, Counter, Gauge,
-                       Histogram, NULL_TRACER, Registry, TraceRecorder,
+from repro.obs import (ANNOTATION_PREFIX, ENGINE_TRACK, QUANTA_BUCKETS,
+                       REQ_TRACK_BASE, SCHED_TRACK, TERMINAL_STATES, Counter,
+                       Gauge, Histogram, NULL_TRACER, Registry, TraceRecorder,
                        validate_chrome)
 from repro.serve import (BlockTables, FaultPlan, FaultyEngine, PagePool,
                          PoolExhausted, Request, Scheduler, State,
@@ -143,6 +143,7 @@ def test_disabled_recorder_allocates_nothing():
     with rec.span("w"):
         pass
     rec.lifecycle(0, "QUEUED")
+    rid = 7
     tracemalloc.start()
     i = 0
     while i < 20000:    # small ints are interned: the loop itself is free
@@ -152,6 +153,19 @@ def test_disabled_recorder_allocates_nothing():
         with rec.span("s"):
             pass
         rec.lifecycle(1, "FINISHED")
+        # the engine's call sites: args built only when the recorder is on
+        with rec.span("engine.admit", "engine", 3,
+                      {"rid": rid} if rec else None):
+            with rec.span("admit.first_token", "engine", 3):
+                pass
+        with rec.span("decode.block", "engine", ENGINE_TRACK,
+                      {"slots": 2, "n": 4} if rec else None):
+            with rec.span("decode.launch", "engine", ENGINE_TRACK):
+                pass
+            with rec.span("decode.fetch", "engine", ENGINE_TRACK):
+                pass
+            with rec.span("decode.guard", "engine", ENGINE_TRACK):
+                pass
         i += 1
     current, peak = tracemalloc.get_traced_memory()
     tracemalloc.stop()
@@ -181,6 +195,44 @@ def test_span_nesting_per_track_and_quantum_stamp():
     assert by_name["outer"]["dur"] >= by_name["inner"]["dur"]
     with pytest.raises(RuntimeError):
         rec.end(0)                      # nothing left open on this track
+
+
+def test_enabled_recorder_annotates_the_profiler(tmp_path):
+    """Under jax.profiler an enabled recorder's spans appear as host events
+    named ``repro.<span>`` that nest as the spans do; instant events stay
+    in the recorder."""
+    import jax
+    from jax.profiler import ProfileData
+    rec = TraceRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("outer", tid=0):
+            rec.event("mark", tid=0)
+            with rec.span("inner", tid=0):
+                with rec.span("other", tid=1):
+                    pass
+            with rec.span("inner", tid=0):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("**/*.xplane.pb"))
+    host = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith(ANNOTATION_PREFIX)]
+    names = sorted(n for n, _, _ in host)
+    assert names == ["repro.inner", "repro.inner", "repro.other",
+                     "repro.outer"]
+    (_, o0, o1), = [h for h in host if h[0] == "repro.outer"]
+    inner = [h for h in host if h[0] == "repro.inner"]
+    (_, x0, x1), = [h for h in host if h[0] == "repro.other"]
+    assert all(o0 <= a <= b <= o1 for _, a, b in inner)
+    assert inner[0][1] <= x0 <= x1 <= inner[0][2]
+    assert not inner[0][2] > inner[1][1] > inner[0][1]   # siblings
+    # the recorder's own view is unchanged
+    assert [e["name"] for e in rec.events()] == [
+        "mark", "other", "inner", "inner", "outer"]
 
 
 def test_ring_buffer_drops_oldest():
@@ -494,3 +546,66 @@ def test_real_engine_traced_fault_run(tmp_path):
     out = tmp_path / "TRACE_serve.json"
     trace.dump(str(out))
     validate_chrome(json.loads(out.read_text()))
+
+
+def test_engine_spans_time_the_host_work():
+    """A traced tiny engine's admission and decode record the spans the
+    benchmark reads: ``engine.admit`` holding its ``prefill.chunk`` and
+    ``admit.first_token`` children, and per decode attempt one
+    ``decode.launch`` (whose total is the decode device-seconds counter),
+    ``decode.fetch`` and ``decode.guard`` inside ``decode.block``; a NaN
+    rescue opens a second set."""
+    import jax
+    from repro.configs import get_config
+    from repro.models import model as M
+    from repro.serve import PagedEngine
+
+    cfg = get_config("qwen3-0.6b").reduced()
+    params = M.lm_init(jax.random.PRNGKey(0), cfg)
+    trace = TraceRecorder()
+    eng = PagedEngine(cfg, params, slots=2, num_pages=12, page_size=8,
+                      max_len=48, chunk=8, decode_block=4, trace=trace)
+
+    class PoisonOnce:           # the fault hook's seam: one NaN block
+        done = False
+
+        def corrupt_logits(self, lg, site):
+            if self.done:
+                return lg
+            self.done = True
+            lg = lg.copy()
+            lg[0, 0, 0] = np.nan
+            return lg
+
+    eng.admit(0, Request(rid=5, prompt=list(range(1, 20)), gen=9))
+    eng.decode([0])
+    eng.fault_hook = PoisonOnce()
+    eng.decode([0])
+    assert eng.nan_rescues == 1
+    spans = [e for e in trace.events() if e["ph"] == "X"]
+    names = [e["name"] for e in spans]
+    for n in ("engine.admit", "admit.first_token", "decode.launch",
+              "decode.fetch", "decode.guard"):
+        assert n in names, n
+    # two blocks, one rescued: three attempts of launch, fetch and guard
+    assert names.count("decode.block") == 2
+    assert [names.count(n) for n in
+            ("decode.launch", "decode.fetch", "decode.guard")] == [3, 3, 3]
+
+    def inside(child, parent):
+        return parent["tid"] == child["tid"] and parent["ts"] <= child["ts"] \
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+
+    admit, = [e for e in spans if e["name"] == "engine.admit"]
+    assert admit["tid"] == 0 and admit["args"] == {"rid": 5}
+    kids = [e for e in spans if e["name"] in ("prefill.chunk",
+                                              "admit.first_token")]
+    assert len(kids) == 4 and all(inside(k, admit) for k in kids)
+    blocks = [e for e in spans if e["name"] == "decode.block"]
+    for e in spans:
+        if e["name"].startswith("decode.") and e["name"] != "decode.block":
+            assert e["tid"] == ENGINE_TRACK
+            assert any(inside(e, b) for b in blocks)
+    launch_s = 1e-6 * sum(e["dur"] for e in spans
+                          if e["name"] == "decode.launch")
+    assert launch_s == pytest.approx(eng.decode_device_s, rel=0.01)
