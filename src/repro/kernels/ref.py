@@ -124,12 +124,16 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 # --- paged pools -------------------------------------------------------------
 
-def paged_view(pool: jax.Array, block_table: jax.Array) -> jax.Array:
+def paged_view(pool: jax.Array, block_table: jax.Array,
+               layer: jax.Array | None = None) -> jax.Array:
     """Gather a page pool through a block table into the contiguous per-slot
     view: pool (P, Hkv, page_size, ...) with block_table (B, npp) ->
     (B, npp * page_size, Hkv, ...).  Serves K/V pools and their (P, Hkv,
-    page_size) scale pools alike."""
-    g = jnp.swapaxes(pool[block_table.astype(jnp.int32)], 2, 3)
+    page_size) scale pools alike.  With ``layer`` the pool is the layer
+    stack (L, P, Hkv, page_size, ...) and the view is gathered straight
+    out of layer ``layer``'s pages."""
+    bt = block_table.astype(jnp.int32)
+    g = jnp.swapaxes(pool[bt] if layer is None else pool[layer, bt], 2, 3)
     b, npp, ps = g.shape[:3]
     return g.reshape((b, npp * ps) + g.shape[3:])
 

@@ -214,19 +214,25 @@ def _ffn_scope(cfg: ModelConfig) -> str:
     return "moe" if cfg.n_experts else "ffn"
 
 
-def _pool_set(pool, page, row, val):
+def _pool_set(pool, page, row, val, layer=None):
     """Write per-row values (pos.shape + (Hkv, ...)) into a head-major pool
-    (P, Hkv, ps, ...) at physical (page, row)."""
-    return pool.at[page, :, row].set(val)
+    (P, Hkv, ps, ...) at physical (page, row); with ``layer``, into layer
+    ``layer`` of the stack (L, P, Hkv, ps, ...) in place."""
+    if layer is None:
+        return pool.at[page, :, row].set(val)
+    return pool.at[layer, page, :, row].set(val)
 
 
 def attn_block_decode_paged(p, x, cfg: ModelConfig, cache, *, kind: str, pos,
-                            block_table, write_mask=None,
+                            block_table, write_mask=None, layer=None,
                             shard: ShardCtx = NOSHARD):
     """Paged twin of attn_block_decode: the new row scatters through the
     block table into the shared pool and attention reads the pool through
     the same table.  cache: {'k','v'[,'k_scale','v_scale']} pools
-    (P,kv,ps,hd); block_table: (B, npp) int32; pos: (B,).
+    (P,kv,ps,hd); block_table: (B, npp) int32; pos: (B,).  ``layer``
+    (scalar int32) makes the cache leaves the layer stacks (L,P,kv,ps,hd):
+    the block reads and writes layer ``layer``'s pages in place and
+    returns the updated stacks.
 
     ``write_mask`` (B,) bool suppresses a slot's cache write (the
     speculative draft scan pads every slot to the batch-max draft length;
@@ -234,7 +240,7 @@ def attn_block_decode_paged(p, x, cfg: ModelConfig, cache, *, kind: str, pos,
     table lookup CLAMPS and would alias a live page — so the page is routed
     to NULL before the scatter)."""
     window = cfg.window if kind == ATTN_LOCAL else None
-    ps = cache["k"].shape[2]
+    ps = cache["k"].shape[-2]
     quant = "k_scale" in cache
     kscale = vscale = None
     with jax.named_scope("attention"):
@@ -250,16 +256,17 @@ def attn_block_decode_paged(p, x, cfg: ModelConfig, cache, *, kind: str, pos,
                 from repro.quant.qtypes import quantize_kv
                 k_upd, ks_new = quantize_kv(k_upd.astype(jnp.float32))
                 v_upd, vs_new = quantize_kv(v_upd.astype(jnp.float32))
-                kscale = _pool_set(cache["k_scale"], page, row, ks_new)
-                vscale = _pool_set(cache["v_scale"], page, row, vs_new)
-            kc = _pool_set(cache["k"], page, row, k_upd)
-            vc = _pool_set(cache["v"], page, row, v_upd)
+                kscale = _pool_set(cache["k_scale"], page, row, ks_new, layer)
+                vscale = _pool_set(cache["v_scale"], page, row, vs_new, layer)
+            kc = _pool_set(cache["k"], page, row, k_upd, layer)
+            vc = _pool_set(cache["v"], page, row, v_upd, layer)
         with jax.named_scope("core"):
             o = L.paged_decode_attention(q, kc, vc, block_table, pos,
                                          window=window,
                                          backend=cfg.decode_backend,
                                          cfg=cfg.decode_attn_cfg,
-                                         k_scale=kscale, v_scale=vscale)
+                                         k_scale=kscale, v_scale=vscale,
+                                         layer=layer)
         with jax.named_scope("out"):
             o = o.reshape(x.shape[0], 1, -1) \
                 @ L.asdense(p["attn"]["wo"], x.dtype)
@@ -337,7 +344,7 @@ def attn_block_prefill_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
 
 
 def attn_block_verify_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
-                            pos0, block_table, valid_len=None,
+                            pos0, block_table, valid_len=None, layer=None,
                             shard: ShardCtx = NOSHARD):
     """Batched-verify twin of attn_block_decode_paged (speculative decode):
     T drafted rows per slot scatter through the block table at positions
@@ -349,9 +356,10 @@ def attn_block_verify_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
     gathers, so a padded row past the slot's page coverage would resolve
     the table lookup to a LIVE page and the scatter would corrupt it —
     route the page to NULL before the scatter instead (scatters to row 0
-    of the null page are harmless by construction)."""
+    of the null page are harmless by construction).  ``layer`` as in
+    attn_block_decode_paged."""
     b, t, _ = x.shape
-    ps = cache["k"].shape[2]
+    ps = cache["k"].shape[-2]
     window = cfg.window if kind == ATTN_LOCAL else None
     pos = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None]     # (B,T)
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
@@ -367,17 +375,17 @@ def attn_block_verify_paged(p, x, cfg: ModelConfig, cache, *, kind: str,
         kq, ks_new = quantize_kv(k.astype(jnp.float32))
         vq, vs_new = quantize_kv(v.astype(jnp.float32))
         k_upd, v_upd = jax.lax.optimization_barrier((kq, vq))
-        kscale = _pool_set(cache["k_scale"], page, row, ks_new)
-        vscale = _pool_set(cache["v_scale"], page, row, vs_new)
+        kscale = _pool_set(cache["k_scale"], page, row, ks_new, layer)
+        vscale = _pool_set(cache["v_scale"], page, row, vs_new, layer)
     else:
         k_upd, v_upd = jax.lax.optimization_barrier(
             (k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)))
-    kc = _pool_set(cache["k"], page, row, k_upd)
-    vc = _pool_set(cache["v"], page, row, v_upd)
+    kc = _pool_set(cache["k"], page, row, k_upd, layer)
+    vc = _pool_set(cache["v"], page, row, v_upd, layer)
     o = L.verify_attention(q, kc, vc, block_table, pos0, window=window,
                            backend=cfg.decode_backend,
                            cfg=cfg.decode_attn_cfg,
-                           k_scale=kscale, v_scale=vscale)
+                           k_scale=kscale, v_scale=vscale, layer=layer)
     o = o.reshape(b, t, -1) @ L.asdense(p["attn"]["wo"], x.dtype)
     x = x + o
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
@@ -439,46 +447,54 @@ def _rglru_out(p, dtype):
 
 
 def rglru_block_decode(p, x, cfg: ModelConfig, cache, *, pos):
-    h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    branch = h @ p["wx"].astype(h.dtype)           # (B,1,dr)
-    gate = h @ p["wgate"].astype(h.dtype)
-    yt, conv_state = L.causal_conv1d_step(p["conv"], cache["conv"], branch[:, 0])
-    bx = yt[:, None]
-    r = (bx @ p["wr"].astype(bx.dtype)) + p["br"].astype(bx.dtype)
-    i = (bx @ p["wi"].astype(bx.dtype)) + p["bi"].astype(bx.dtype)
-    rg = jax.nn.sigmoid(r.astype(jnp.float32))
-    ig = jax.nn.sigmoid(i.astype(jnp.float32))
-    from repro.kernels.ref import RGLRU_C
-    log_a = -RGLRU_C * jax.nn.softplus(p["a_param"])[None, None] * rg
-    a_t = jnp.exp(log_a)
-    mult = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-12))
-    hnew = a_t[:, 0] * cache["h"] + mult[:, 0] * (
-        ig[:, 0] * bx[:, 0].astype(jnp.float32))
-    y = (hnew[:, None].astype(x.dtype) * jax.nn.gelu(gate)) @ _rglru_out(p, x.dtype)
-    x = x + y
-    h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    return x + L.ffn(p["ffn"], h, backend=cfg.ffn_backend), \
-        {"conv": conv_state, "h": hnew}
+    with jax.named_scope("recurrent"):
+        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        branch = h @ p["wx"].astype(h.dtype)           # (B,1,dr)
+        gate = h @ p["wgate"].astype(h.dtype)
+        yt, conv_state = L.causal_conv1d_step(p["conv"], cache["conv"],
+                                              branch[:, 0])
+        bx = yt[:, None]
+        r = (bx @ p["wr"].astype(bx.dtype)) + p["br"].astype(bx.dtype)
+        i = (bx @ p["wi"].astype(bx.dtype)) + p["bi"].astype(bx.dtype)
+        rg = jax.nn.sigmoid(r.astype(jnp.float32))
+        ig = jax.nn.sigmoid(i.astype(jnp.float32))
+        from repro.kernels.ref import RGLRU_C
+        log_a = -RGLRU_C * jax.nn.softplus(p["a_param"])[None, None] * rg
+        a_t = jnp.exp(log_a)
+        mult = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-12))
+        hnew = a_t[:, 0] * cache["h"] + mult[:, 0] * (
+            ig[:, 0] * bx[:, 0].astype(jnp.float32))
+        y = (hnew[:, None].astype(x.dtype) * jax.nn.gelu(gate)) \
+            @ _rglru_out(p, x.dtype)
+        x = x + y
+    with jax.named_scope("ffn"):
+        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+        x = x + L.ffn(p["ffn"], h, backend=cfg.ffn_backend)
+    return x, {"conv": conv_state, "h": hnew}
 
 
 def rglru_block_prefill(p, x, cfg: ModelConfig, cache, *, pos0):
     """Chunked prefill: run T tokens through the recurrence starting from
     the cached (conv, h) state and return the advanced state."""
-    h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    branch = h @ p["wx"].astype(h.dtype)
-    gate = h @ p["wgate"].astype(h.dtype)
-    bx, conv_state = L.causal_conv1d_prefill(p["conv"], cache["conv"], branch)
-    r = (bx @ p["wr"].astype(bx.dtype)) + p["br"].astype(bx.dtype)
-    i = (bx @ p["wi"].astype(bx.dtype)) + p["bi"].astype(bx.dtype)
-    from repro.kernels import ref as KREF
-    hseq, h_last = KREF.rglru_with_state(
-        bx.astype(jnp.float32), r.astype(jnp.float32), i.astype(jnp.float32),
-        p["a_param"], cache["h"])
-    y = (hseq.astype(x.dtype) * jax.nn.gelu(gate)) @ _rglru_out(p, x.dtype)
-    x = x + y
-    h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    return x + L.ffn(p["ffn"], h, backend=cfg.ffn_backend), \
-        {"conv": conv_state, "h": h_last}
+    with jax.named_scope("recurrent"):
+        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        branch = h @ p["wx"].astype(h.dtype)
+        gate = h @ p["wgate"].astype(h.dtype)
+        bx, conv_state = L.causal_conv1d_prefill(p["conv"], cache["conv"],
+                                                 branch)
+        r = (bx @ p["wr"].astype(bx.dtype)) + p["br"].astype(bx.dtype)
+        i = (bx @ p["wi"].astype(bx.dtype)) + p["bi"].astype(bx.dtype)
+        from repro.kernels import ref as KREF
+        hseq, h_last = KREF.rglru_with_state(
+            bx.astype(jnp.float32), r.astype(jnp.float32),
+            i.astype(jnp.float32), p["a_param"], cache["h"])
+        y = (hseq.astype(x.dtype) * jax.nn.gelu(gate)) \
+            @ _rglru_out(p, x.dtype)
+        x = x + y
+    with jax.named_scope("ffn"):
+        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+        x = x + L.ffn(p["ffn"], h, backend=cfg.ffn_backend)
+    return x, {"conv": conv_state, "h": h_last}
 
 
 def rglru_cache_init(cfg: ModelConfig, b: int, dtype=jnp.bfloat16):
@@ -539,62 +555,69 @@ def mamba_block(p, x, cfg: ModelConfig, *, shard: ShardCtx = NOSHARD):
 
 
 def mamba_block_decode(p, x, cfg: ModelConfig, cache, *, pos):
-    b = x.shape[0]
-    din, g, n, hh = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state, cfg.ssm_heads
-    ph = cfg.ssm_headdim
-    h0 = L.rmsnorm(p["ln"], x, cfg.norm_eps)
-    zxbcdt = (h0 @ p["in_proj"].astype(h0.dtype))[:, 0]
-    z, xbc, dt_raw = _mamba_split(cfg, zxbcdt)
-    yt, conv_state = L.causal_conv1d_step(p["conv"], cache["conv"], xbc)
-    xbc = jax.nn.silu(yt)
-    xs, bc = jnp.split(xbc, [din], axis=-1)
-    bmat, cmat = jnp.split(bc, 2, axis=-1)                    # (B, g*n)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])   # (B,H)
-    a = -jnp.exp(p["a_log"])                                  # (H,)
-    xh = xs.reshape(b, hh, ph).astype(jnp.float32)
-    rep = hh // g
-    bm = jnp.repeat(bmat.reshape(b, g, n), rep, axis=1).astype(jnp.float32)
-    cm = jnp.repeat(cmat.reshape(b, g, n), rep, axis=1).astype(jnp.float32)
-    dA = jnp.exp(dt * a[None])                                # (B,H)
-    state = cache["ssm"] * dA[..., None, None] + jnp.einsum(
-        "bhn,bhp->bhpn", bm * dt[..., None], xh)
-    y = jnp.einsum("bhn,bhpn->bhp", cm, state)
-    y = y + xh * p["d_skip"][None, :, None]
-    y = y.reshape(b, 1, din).astype(x.dtype)
-    y = L.rmsnorm(p["gnorm"], y * jax.nn.silu(z[:, None]), cfg.norm_eps)
-    return x + y @ p["out_proj"].astype(x.dtype), \
-        {"conv": conv_state, "ssm": state}
+    with jax.named_scope("ssm"):
+        b = x.shape[0]
+        din, g, n = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state
+        hh = cfg.ssm_heads
+        ph = cfg.ssm_headdim
+        h0 = L.rmsnorm(p["ln"], x, cfg.norm_eps)
+        zxbcdt = (h0 @ p["in_proj"].astype(h0.dtype))[:, 0]
+        z, xbc, dt_raw = _mamba_split(cfg, zxbcdt)
+        yt, conv_state = L.causal_conv1d_step(p["conv"], cache["conv"], xbc)
+        xbc = jax.nn.silu(yt)
+        xs, bc = jnp.split(xbc, [din], axis=-1)
+        bmat, cmat = jnp.split(bc, 2, axis=-1)                    # (B, g*n)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                             + p["dt_bias"])                       # (B,H)
+        a = -jnp.exp(p["a_log"])                                  # (H,)
+        xh = xs.reshape(b, hh, ph).astype(jnp.float32)
+        rep = hh // g
+        bm = jnp.repeat(bmat.reshape(b, g, n), rep, axis=1).astype(jnp.float32)
+        cm = jnp.repeat(cmat.reshape(b, g, n), rep, axis=1).astype(jnp.float32)
+        dA = jnp.exp(dt * a[None])                                # (B,H)
+        state = cache["ssm"] * dA[..., None, None] + jnp.einsum(
+            "bhn,bhp->bhpn", bm * dt[..., None], xh)
+        y = jnp.einsum("bhn,bhpn->bhp", cm, state)
+        y = y + xh * p["d_skip"][None, :, None]
+        y = y.reshape(b, 1, din).astype(x.dtype)
+        y = L.rmsnorm(p["gnorm"], y * jax.nn.silu(z[:, None]), cfg.norm_eps)
+        return x + y @ p["out_proj"].astype(x.dtype), \
+            {"conv": conv_state, "ssm": state}
 
 
 def mamba_block_prefill(p, x, cfg: ModelConfig, cache, *, pos0):
     """Chunked prefill: advance (conv, ssm) state over T tokens at once via
     the chunked SSD scan seeded with the cached state."""
-    b, t, _ = x.shape
-    din, g, n, hh = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state, cfg.ssm_heads
-    ph = cfg.ssm_headdim
-    h0 = L.rmsnorm(p["ln"], x, cfg.norm_eps)
-    zxbcdt = h0 @ p["in_proj"].astype(h0.dtype)
-    z, xbc, dt_raw = _mamba_split(cfg, zxbcdt)
-    yconv, conv_state = L.causal_conv1d_prefill(p["conv"], cache["conv"], xbc)
-    xbc = jax.nn.silu(yconv)
-    xs, bc = jnp.split(xbc, [din], axis=-1)
-    bmat, cmat = jnp.split(bc, 2, axis=-1)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])   # (B,T,H)
-    a = -jnp.exp(p["a_log"])
-    from repro.kernels import ref as KREF
-    xh = xs.reshape(b, t, hh, ph).astype(jnp.float32)
-    chunk = 64 if t % 64 == 0 else (16 if t % 16 == 0 else 1)
-    y, state = KREF.ssd_chunked(
-        xh, dt, a,
-        bmat.reshape(b, t, g, n).astype(jnp.float32),
-        cmat.reshape(b, t, g, n).astype(jnp.float32),
-        chunk=chunk, state0=cache["ssm"].transpose(0, 1, 3, 2),
-        return_state=True)
-    y = y + xh * p["d_skip"][None, None, :, None]
-    y = y.reshape(b, t, din).astype(x.dtype)
-    y = L.rmsnorm(p["gnorm"], y * jax.nn.silu(z), cfg.norm_eps)
-    return x + y @ p["out_proj"].astype(x.dtype), \
-        {"conv": conv_state, "ssm": state.transpose(0, 1, 3, 2)}
+    with jax.named_scope("ssm"):
+        b, t, _ = x.shape
+        din, g, n = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state
+        hh = cfg.ssm_heads
+        ph = cfg.ssm_headdim
+        h0 = L.rmsnorm(p["ln"], x, cfg.norm_eps)
+        zxbcdt = h0 @ p["in_proj"].astype(h0.dtype)
+        z, xbc, dt_raw = _mamba_split(cfg, zxbcdt)
+        yconv, conv_state = L.causal_conv1d_prefill(p["conv"], cache["conv"],
+                                                    xbc)
+        xbc = jax.nn.silu(yconv)
+        xs, bc = jnp.split(xbc, [din], axis=-1)
+        bmat, cmat = jnp.split(bc, 2, axis=-1)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                             + p["dt_bias"])                     # (B,T,H)
+        a = -jnp.exp(p["a_log"])
+        from repro.kernels import ref as KREF
+        xh = xs.reshape(b, t, hh, ph).astype(jnp.float32)
+        chunk = 64 if t % 64 == 0 else (16 if t % 16 == 0 else 1)
+        y, state = KREF.ssd_chunked(
+            xh, dt, a,
+            bmat.reshape(b, t, g, n).astype(jnp.float32),
+            cmat.reshape(b, t, g, n).astype(jnp.float32),
+            chunk=chunk, state0=cache["ssm"].transpose(0, 1, 3, 2),
+            return_state=True)
+        y = y + xh * p["d_skip"][None, None, :, None]
+        y = y.reshape(b, t, din).astype(x.dtype)
+        y = L.rmsnorm(p["gnorm"], y * jax.nn.silu(z), cfg.norm_eps)
+        return x + y @ p["out_proj"].astype(x.dtype), \
+            {"conv": conv_state, "ssm": state.transpose(0, 1, 3, 2)}
 
 
 def mamba_cache_init(cfg: ModelConfig, b: int, dtype=jnp.bfloat16):

@@ -455,9 +455,16 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=None, scale=None,
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
+def _layer_pool(pool, layer):
+    """Layer ``layer``'s pool out of a stack (None passes through): what
+    the paged kernels, which take one layer's pool, are handed."""
+    return pool if pool is None or layer is None else pool[layer]
+
+
 def paged_decode_attention(q, k_pool, v_pool, block_table, pos, *,
                            window=None, scale=None, backend: str = "ref",
-                           cfg="auto", k_scale=None, v_scale=None):
+                           cfg="auto", k_scale=None, v_scale=None,
+                           layer=None):
     """Single-token attention against a PAGED cache.  q: (B,1,H,D);
     pools: (P, Hkv, page_size, D) shared by every slot; block_table:
     (B, npp) int32 per-slot logical->physical page map; pos: (B,).
@@ -472,9 +479,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, *,
     ``k_scale``/``v_scale`` (P, Hkv, page_size) mark int8 pools
     (cfg.kv_quant="int8"): dequant is fused into the kernel pass; the
     fallback dequantizes the gathered view first.
+
+    ``layer`` (scalar int32) makes the pools and scales the layer stacks
+    (L, P, ...): the fallback gathers layer ``layer``'s view straight out
+    of the stack; the kernel takes that layer's pool.
     """
     b, _, h, d = q.shape
-    n_pages, hkv, ps, _ = k_pool.shape
+    hkv, ps = k_pool.shape[-3:-1]
     npp = block_table.shape[1]
     if backend == "pallas" and h % hkv == 0:
         from repro.kernels import ops
@@ -488,15 +499,17 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, *,
         # an explicit degree the per-slot page count can't tile falls back
         if npp % rcfg.degree == 0:
             return ops.paged_decode_attention(
-                q, k_pool, v_pool, block_table, pos, rcfg, window=window,
-                scale=scale, k_scale=k_scale, v_scale=v_scale)
+                q, _layer_pool(k_pool, layer), _layer_pool(v_pool, layer),
+                block_table, pos, rcfg, window=window, scale=scale,
+                k_scale=_layer_pool(k_scale, layer),
+                v_scale=_layer_pool(v_scale, layer))
     # gather-to-contiguous fallback (and the paged kernel's parity oracle)
-    k_view = KREF.paged_view(k_pool, block_table)
-    v_view = KREF.paged_view(v_pool, block_table)
+    k_view = KREF.paged_view(k_pool, block_table, layer)
+    v_view = KREF.paged_view(v_pool, block_table, layer)
     ks = vs = None
     if k_scale is not None:
-        ks = KREF.paged_view(k_scale, block_table)
-        vs = KREF.paged_view(v_scale, block_table)
+        ks = KREF.paged_view(k_scale, block_table, layer)
+        vs = KREF.paged_view(v_scale, block_table, layer)
     return decode_attention(q, k_view, v_view, pos, window=window,
                             scale=scale, backend="ref",
                             k_scale=ks, v_scale=vs)
@@ -504,10 +517,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, *,
 
 def verify_attention(q, k_pool, v_pool, block_table, pos0, *, window=None,
                      scale=None, backend: str = "ref", cfg="auto",
-                     k_scale=None, v_scale=None):
+                     k_scale=None, v_scale=None, layer=None):
     """Batched-verify attention against a PAGED cache (speculative decode).
     q: (B,T,H,D) — row t attends at cache position ``pos0[b] + t``; pools /
-    block_table / scales as in `paged_decode_attention`; pos0: (B,).
+    block_table / scales / layer as in `paged_decode_attention`; pos0: (B,).
 
     backend="pallas" dispatches the short-q block-table kernel
     (kernels/decode_attention.make_verify_kernel, tuned under the
@@ -520,7 +533,7 @@ def verify_attention(q, k_pool, v_pool, block_table, pos0, *, window=None,
     sequential decode on the ref backend.
     """
     b, t, h, d = q.shape
-    n_pages, hkv, ps, _ = k_pool.shape
+    hkv, ps = k_pool.shape[-3:-1]
     npp = block_table.shape[1]
     if backend == "pallas" and h % hkv == 0:
         from repro.kernels import ops
@@ -534,14 +547,18 @@ def verify_attention(q, k_pool, v_pool, block_table, pos0, *, window=None,
         # an explicit degree the per-slot page count can't tile falls back
         if npp % rcfg.degree == 0:
             return ops.flash_attention_verify(
-                q, k_pool, v_pool, block_table, pos0, rcfg, window=window,
-                scale=scale, k_scale=k_scale, v_scale=v_scale)
+                q, _layer_pool(k_pool, layer), _layer_pool(v_pool, layer),
+                block_table, pos0, rcfg, window=window, scale=scale,
+                k_scale=_layer_pool(k_scale, layer),
+                v_scale=_layer_pool(v_scale, layer))
     # gather-to-contiguous fallback (and the verify kernel's parity oracle)
-    k_view = KREF.paged_view(k_pool, block_table)
-    v_view = KREF.paged_view(v_pool, block_table)
+    k_view = KREF.paged_view(k_pool, block_table, layer)
+    v_view = KREF.paged_view(v_pool, block_table, layer)
     if k_scale is not None:
-        k_view = dequantize_kv(k_view, KREF.paged_view(k_scale, block_table))
-        v_view = dequantize_kv(v_view, KREF.paged_view(v_scale, block_table))
+        k_view = dequantize_kv(
+            k_view, KREF.paged_view(k_scale, block_table, layer))
+        v_view = dequantize_kv(
+            v_view, KREF.paged_view(v_scale, block_table, layer))
     s = npp * ps
     g = h // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)

@@ -58,14 +58,14 @@ def _block_apply(kind: str, p, x, cfg, *, pos, mrope_pos3, shard, moe_capacity,
 
 
 def _block_decode(kind: str, p, x, cfg, cache, *, pos, shard,
-                  block_table=None, write_mask=None):
+                  block_table=None, write_mask=None, layer=None):
     if kind in (ATTN_GLOBAL, ATTN_LOCAL):
         if block_table is not None:
             return B.attn_block_decode_paged(p, x, cfg, cache, kind=kind,
                                              pos=pos,
                                              block_table=block_table,
                                              write_mask=write_mask,
-                                             shard=shard)
+                                             layer=layer, shard=shard)
         return B.attn_block_decode(p, x, cfg, cache, kind=kind, pos=pos,
                                    shard=shard)
     if kind == RECURRENT:
@@ -76,11 +76,12 @@ def _block_decode(kind: str, p, x, cfg, cache, *, pos, shard,
 
 
 def _block_verify(kind: str, p, x, cfg, cache, *, pos0, block_table,
-                  valid_len, shard):
+                  valid_len, shard, layer=None):
     if kind in (ATTN_GLOBAL, ATTN_LOCAL):
         return B.attn_block_verify_paged(p, x, cfg, cache, kind=kind,
                                          pos0=pos0, block_table=block_table,
-                                         valid_len=valid_len, shard=shard)
+                                         valid_len=valid_len, layer=layer,
+                                         shard=shard)
     # recurrent/SSM state is a running summary — rejected drafted tokens
     # cannot be rolled out of it, so speculative decoding is attention-only
     raise NotImplementedError(
@@ -405,21 +406,51 @@ def lm_init_cache_paged(cfg: ModelConfig, b: int, num_pages: int,
     }
 
 
-def _cache_layer(caches, i):
-    """Layer ``i``'s slice of each stacked cache (the scan's per-layer
-    read of the carried stack)."""
+def _cache_layer(cache, i):
+    """Layer ``i``'s slice of a stacked cache (the scan's per-layer read
+    of the carried stack)."""
     with jax.named_scope("cache_io"):
-        return [jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), c)
-            for c in caches]
+        return jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), cache)
 
 
-def _cache_layer_set(caches, new, i):
-    """The stacked caches with layer ``i`` replaced by ``new``."""
+def _cache_layer_set(cache, new, i):
+    """The stacked cache with layer ``i`` replaced by ``new``."""
     with jax.named_scope("cache_io"):
-        return [jax.tree.map(
-            lambda a, u: lax.dynamic_update_index_in_dim(a, u, i, 0), c, nc)
-            for c, nc in zip(caches, new)]
+        return jax.tree.map(
+            lambda a, u: lax.dynamic_update_index_in_dim(a, u, i, 0),
+            cache, new)
+
+
+def _scan_cached(blocks, caches, x, kinds, paged, block_fn):
+    """Scan the period-stacked ``blocks`` over ``x`` with the stacked
+    ``caches`` in the scan CARRY (stacking them as scan ys instead makes
+    XLA materialize a second full-cache buffer).
+
+    ``block_fn(kind, p, x, cache, layer) -> (x, new cache)``.  With
+    ``paged`` (the attention caches are page pools read through a block
+    table), an attention entry gets the stacked pools and the layer index
+    and reads and writes its pages in place: a layer's pool is never
+    copied out of the stack.  Every other entry (per-slot recurrent/SSM/
+    conv state, contiguous K/V) gets layer ``i``'s slice (``layer=None``),
+    written back after the block."""
+    def period_body(carry, pblk):
+        x, caches, i = carry
+        newc = []
+        for j, kind in enumerate(kinds):
+            if paged and kind in (ATTN_GLOBAL, ATTN_LOCAL):
+                x, nc = block_fn(kind, pblk[j], x, caches[j], i)
+            else:
+                x, nc = block_fn(kind, pblk[j], x,
+                                 _cache_layer(caches[j], i), None)
+                nc = _cache_layer_set(caches[j], nc, i)
+            newc.append(nc)
+        return (x, newc, i + 1), None
+
+    (x, new_blocks, _), _ = lax.scan(
+        period_body, (x, list(caches), jnp.asarray(0, jnp.int32)),
+        tuple(blocks))
+    return x, list(new_blocks)
 
 
 def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
@@ -436,30 +467,15 @@ def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
     with jax.named_scope("embed"):
         x = _embed(params, tokens, cfg, {"tokens": tokens})
 
-    kinds = period
+    def block_fn(kind, p, x, c, layer):
+        if cfg.is_encdec:
+            return B.dec_block_decode(p, x, cfg, {**c}, pos=pos)
+        return _block_decode(kind, p, x, cfg, c, pos=pos, shard=shard,
+                             block_table=block_table, write_mask=write_mask,
+                             layer=layer)
 
-    # the stacked caches ride in the scan CARRY and are updated in place
-    # (dynamic_update_index_in_dim); stacking them as scan ys instead makes
-    # XLA materialize a second full-cache buffer (observed as an f32 copy).
-    def period_body(carry, pblk):
-        x, caches, i = carry
-        cblk = _cache_layer(caches, i)
-        newc = []
-        for j, kind in enumerate(kinds):
-            if cfg.is_encdec:
-                x, nc = B.dec_block_decode(pblk[j], x, cfg,
-                                           {**cblk[j]}, pos=pos)
-            else:
-                x, nc = _block_decode(kind, pblk[j], x, cfg, cblk[j],
-                                      pos=pos, shard=shard,
-                                      block_table=block_table,
-                                      write_mask=write_mask)
-            newc.append(nc)
-        return (x, _cache_layer_set(caches, newc, i), i + 1), None
-
-    (x, new_blocks, _), _ = lax.scan(
-        period_body, (x, list(cache["blocks"]), jnp.asarray(0, jnp.int32)),
-        tuple(params["blocks"]))
+    x, new_blocks = _scan_cached(params["blocks"], cache["blocks"], x,
+                                 period, block_table is not None, block_fn)
     new_tail = []
     for p_t, c_t, kind in zip(params["tail"], cache["tail"], tail):
         x, nc = _block_decode(kind, p_t, x, cfg, c_t, pos=pos, shard=shard,
@@ -471,7 +487,7 @@ def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, *,
         logits = (x[:, 0] @ _head(params, cfg).astype(x.dtype)
                   ).astype(jnp.float32)
         logits = logits[:, : cfg.vocab]               # drop pad-vocab ids
-    return logits, {"blocks": list(new_blocks), "tail": new_tail}
+    return logits, {"blocks": new_blocks, "tail": new_tail}
 
 
 def lm_verify_step(params, cache, tokens, pos0, cfg: ModelConfig, *,
@@ -494,22 +510,14 @@ def lm_verify_step(params, cache, tokens, pos0, cfg: ModelConfig, *,
         raise NotImplementedError("lm_verify_step: enc-dec models are not "
                                   "supported")
     x = _embed(params, tokens, cfg, {"tokens": tokens})
-    kinds = period
 
-    def period_body(carry, pblk):
-        x, caches, i = carry
-        cblk = _cache_layer(caches, i)
-        newc = []
-        for j, kind in enumerate(kinds):
-            x, nc = _block_verify(kind, pblk[j], x, cfg, cblk[j], pos0=pos0,
-                                  block_table=block_table,
-                                  valid_len=valid_len, shard=shard)
-            newc.append(nc)
-        return (x, _cache_layer_set(caches, newc, i), i + 1), None
+    def block_fn(kind, p, x, c, layer):
+        return _block_verify(kind, p, x, cfg, c, pos0=pos0,
+                             block_table=block_table, valid_len=valid_len,
+                             shard=shard, layer=layer)
 
-    (x, new_blocks, _), _ = lax.scan(
-        period_body, (x, list(cache["blocks"]), jnp.asarray(0, jnp.int32)),
-        tuple(params["blocks"]))
+    x, new_blocks = _scan_cached(params["blocks"], cache["blocks"], x,
+                                 period, True, block_fn)
     new_tail = []
     for p_t, c_t, kind in zip(params["tail"], cache["tail"], tail):
         x, nc = _block_verify(kind, p_t, x, cfg, c_t, pos0=pos0,
@@ -520,7 +528,7 @@ def lm_verify_step(params, cache, tokens, pos0, cfg: ModelConfig, *,
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = (x @ _head(params, cfg).astype(x.dtype)).astype(jnp.float32)
     logits = logits[:, :, : cfg.vocab]            # drop pad-vocab ids
-    return logits, {"blocks": list(new_blocks), "tail": new_tail}
+    return logits, {"blocks": new_blocks, "tail": new_tail}
 
 
 def _block_prefill(kind: str, p, x, cfg, cache, *, pos0, block_table=None):
@@ -633,32 +641,26 @@ def lm_prefill(params, batch, cfg: ModelConfig, s_max: int | None = None, *,
 
     with jax.named_scope("embed"):
         x = _embed(params, tokens, cfg, batch)
-    kinds = period
 
-    def period_body(carry, pblk):
-        x, caches, i = carry
-        cblk = _cache_layer(caches, i)
-        newc = []
-        for j, kind in enumerate(kinds):
-            if cfg.is_encdec:
-                x, nc = B.dec_block_prefill(pblk[j], x, cfg, {**cblk[j]},
-                                            pos0=pos0)
-            else:
-                x, nc = _block_prefill(kind, pblk[j], x, cfg, cblk[j],
-                                       pos0=pos0, block_table=block_table)
-            newc.append(nc)
-        return (x, _cache_layer_set(caches, newc, i), i + 1), None
+    def block_fn(kind, p, x, c, layer):
+        if cfg.is_encdec:
+            return B.dec_block_prefill(p, x, cfg, {**c}, pos0=pos0)
+        return _block_prefill(kind, p, x, cfg, c, pos0=pos0,
+                              block_table=block_table)
 
-    (x, new_blocks, _), _ = lax.scan(
-        period_body, (x, list(cache["blocks"]), jnp.asarray(0, jnp.int32)),
-        tuple(params["blocks"]))
+    # prefill slices each layer's pool out of the stack: a chunk scatters
+    # rows that span every kv head of a head-major page, and indexing the
+    # stack in place let XLA copy the WHOLE stack to token-major and back
+    # around every layer (measured: qwen1.5-4b-d10 docqa, 20 kv heads)
+    x, new_blocks = _scan_cached(params["blocks"], cache["blocks"], x,
+                                 period, False, block_fn)
     new_tail = []
     for p_t, c_t, kind in zip(params["tail"], cache["tail"], tail):
         x, nc = _block_prefill(kind, p_t, x, cfg, c_t, pos0=pos0,
                                block_table=block_table)
         new_tail.append(nc)
 
-    new_cache = {"blocks": list(new_blocks), "tail": new_tail}
+    new_cache = {"blocks": new_blocks, "tail": new_tail}
     if mask is not None:
         def committed(n, o, kind, batch_axis):
             # paged attention pools have NO batch axis — the null-routed

@@ -7,6 +7,7 @@ The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and every xdist worker imports
 this file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,20 +110,22 @@ def test_matmul_compiles(one_chip):
 # hold outside them: copies, which the compiler makes itself (whole-stack
 # copies, loop-carry and layout copies); ops it makes with no op_name (the
 # weights' bf16 casts hoisted out of the loops, buffer allocations); and
-# the scan machinery engine_decode traces itself (loop counters and the
-# per-step outputs stacked into the block's)
-MODEL_SCOPES = {"embed", "attention", "ffn", "moe", "head", "cache_io"}
+# the scan machinery engine_decode and the layer scan trace themselves
+# (loop counters, the per-step outputs stacked into the block's, and a
+# period's slice of the stacked weights)
+MODEL_SCOPES = {"embed", "attention", "recurrent", "ssm", "ffn", "moe",
+                "head", "cache_io"}
 COPIES = {"copy", "copy-start", "copy-done"}
 COMPILER_MADE = {"convert", "custom-call"}
-SCAN_MACHINERY = {"add", "lt", "dynamic_update_slice", "broadcast_in_dim"}
+SCAN_MACHINERY = {"add", "lt", "dynamic_update_slice", "broadcast_in_dim",
+                  "squeeze"}
 NO_OPS = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
           "while", "conditional"}
 
 
-def _leaf_ops(hlo: str):
-    """(opcode, op_name) of every instruction of the entry computation and
-    the while bodies and conditions: what a device trace shows."""
-    import re
+def _control_lines(hlo: str):
+    """The instructions of the entry computation and of the while bodies
+    and conditions: what a device trace shows."""
     comps, cur = {}, None
     for line in hlo.splitlines():
         m = re.match(r"^(ENTRY )?%(\S+) ", line)
@@ -139,31 +142,64 @@ def _leaf_ops(hlo: str):
             control.update(m.group(1) for m in re.finditer(
                 r"(?:body|condition)=%([\w.\-]+)", line))
     for n in control:
-        for line in comps[n][1]:
-            m = re.match(r"\s*(?:ROOT )?%\S+ = \S+ ([a-z][\w\-]*)\(", line)
-            if m and m.group(1) not in NO_OPS:
-                on = re.search(r'op_name="([^"]*)"', line)
-                yield m.group(1), on.group(1) if on else ""
+        yield from comps[n][1]
 
 
-def test_decode_program_ops_carry_model_scopes(one_chip):
-    """Every leaf op of a tiny engine_decode program compiled for the chip
-    carries a model scope in its op_name, or is one of the listed unscoped
-    kinds; the scopes the device-time reduction reads all appear."""
+def _leaf_ops(hlo: str):
+    """(opcode, op_name) of every control instruction that does work."""
+    for line in _control_lines(hlo):
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \S+ ([a-z][\w\-]*)\(", line)
+        if m and m.group(1) not in NO_OPS:
+            on = re.search(r'op_name="([^"]*)"', line)
+            yield m.group(1), on.group(1) if on else ""
+
+
+def _out_dims(line: str):
+    """The dims of an instruction's array output, or None for a tuple."""
+    m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]", line)
+    return None if m is None else tuple(
+        int(d) for d in m.group(1).split(",") if d)
+
+
+def _engine_program(sharding, arch: str, program: str):
+    """(engine, optimized HLO text) of a tiny PagedEngine's
+    ``engine_decode`` (a block of 4), or a self-drafting SpecPagedEngine's
+    verify pass (4 rows), compiled for the described chip."""
     import numpy as np
     from repro.configs import get_config
     from repro.models import model as M
-    from repro.serve import PagedEngine
+    from repro.serve import PagedEngine, SpecPagedEngine
 
-    cfg = get_config("qwen3-0.6b").reduced()
-    eng = PagedEngine(cfg, M.lm_init(jax.random.PRNGKey(0), cfg), slots=2,
-                      num_pages=9, page_size=8, max_len=32, chunk=8,
-                      decode_block=4)
+    cfg = get_config(arch).reduced()
+    params = M.lm_init(jax.random.PRNGKey(0), cfg)
+    kw = dict(slots=2, num_pages=11, page_size=8, max_len=32, chunk=8,
+              decode_block=4)
+    vec = jnp.zeros((2,), jnp.int32)
+    if program == "engine_decode":
+        eng = PagedEngine(cfg, params, **kw)
+        bt = eng._device_table(np.ones(2, bool))
+        fn, rest = eng._decode_fn(4), (jnp.zeros((2, 1), jnp.int32), vec, bt)
+    else:
+        eng = SpecPagedEngine(cfg, params, spec_k=3, draft_cfg=cfg,
+                              draft_params=params, **kw)
+        bt = eng._device_table(np.ones(2, bool))
+        fn, rest = eng._verify_fn, (jnp.zeros((2, 4), jnp.int32), vec,
+                                    vec + 4, bt)
     args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (eng.params, eng.cache, jnp.zeros((2, 1), jnp.int32),
-         jnp.zeros((2,), jnp.int32), eng._device_table(np.ones(2, bool))))
-    hlo = eng._decode_fn(4).lower(*args).compile().as_text()
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        (eng.params, eng.cache) + rest)
+    return eng, fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "recurrentgemma-2b"])
+def test_decode_program_ops_carry_model_scopes(one_chip, arch):
+    """Every leaf op of a tiny engine_decode program compiled for the chip
+    carries a model scope in its op_name, or is one of the listed unscoped
+    kinds; the scopes the device-time reduction reads all appear.
+    ``cache_io`` (the per-layer slice and write-back of per-slot state)
+    appears exactly when the stack has per-slot state: paged attention
+    pools are read and written in place in the stack."""
+    eng, hlo = _engine_program(one_chip, arch, "engine_decode")
     seen, stray = set(), []
     for op, name in _leaf_ops(hlo):
         parts = name.split("/")
@@ -183,4 +219,27 @@ def test_decode_program_ops_carry_model_scopes(one_chip):
             stray.append((op, name))
     assert not stray, stray
     assert {"embed", "attention/qkv", "attention/kv_write", "attention/core",
-            "cache_io", "ffn", "head"} <= seen, seen
+            "ffn", "head"} <= seen, seen
+    per_slot_state = not all(eng._attn_kinds[0] + eng._attn_kinds[1])
+    assert ("cache_io" in seen) == per_slot_state, seen
+
+
+@pytest.mark.parametrize("program", ["engine_decode", "spec_verify"])
+def test_paged_pools_are_indexed_in_place(one_chip, program):
+    """The compiled decode and speculative verify programs never copy one
+    layer's K/V pool out of the stacked pool and back: no control
+    instruction outputs an array of a single layer pool's shape, and no
+    dynamic-slice or dynamic-update-slice takes the stack.  (Prefill
+    still slices: see models.model.lm_prefill.)"""
+    eng, hlo = _engine_program(one_chip, "qwen3-0.6b", program)
+    stack = eng.cache["blocks"][0]["k"].shape
+    assert stack[0] >= 2, stack
+    layer_pools = [line.strip() for line in _control_lines(hlo)
+                   if _out_dims(line) == stack[1:]]
+    assert not layer_pools, layer_pools
+    dims = {m.group(1): _out_dims(line) for line in hlo.splitlines()
+            if (m := re.match(r"\s*(?:ROOT )?%(\S+) = ", line))}
+    sliced = [line.strip() for line in hlo.splitlines()
+              if (m := re.search(r" dynamic-(?:update-)?slice\(%([\w.\-]+)",
+                                 line)) and dims.get(m.group(1)) == stack]
+    assert not sliced, sliced

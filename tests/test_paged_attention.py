@@ -119,6 +119,30 @@ def test_layers_paged_dispatch_and_fallback(data):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("site", ["decode", "verify"])
+def test_layers_paged_attention_reads_one_layer_of_a_stack(data, site,
+                                                           backend):
+    """Given the layer stack and a layer index, the layer-level paged
+    attention reads only that layer's pages: the result equals the same
+    call on that layer's own pool, while every other layer is poisoned."""
+    q, kp, vp, bt, pos = data
+    poison = jnp.full_like(kp, 1e4)
+    ks, vs = jnp.stack([poison, kp, poison]), jnp.stack([poison, vp, poison])
+    layer = jnp.asarray(1, jnp.int32)
+    if site == "decode":
+        fn, args = L.paged_decode_attention, (q, bt, pos)
+    else:
+        t = 3
+        fn = L.verify_attention
+        args = (jax.random.normal(KEY, (B, t, H, D), jnp.float32), bt,
+                pos - (t - 1))
+    kw = dict(backend=backend, cfg="con2")
+    want = fn(args[0], kp, vp, *args[1:], **kw)
+    got = fn(args[0], ks, vs, *args[1:], layer=layer, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # ---------------------------------------------------------------------------
 # tuner family
 # ---------------------------------------------------------------------------
