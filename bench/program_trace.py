@@ -5,7 +5,8 @@ The program's ``TraceRecorder`` keeps every span in the run's record
 annotation named ``repro.<span>`` on the profiler's clock.  Its model code
 runs under ``jax.named_scope``s (``embed``, ``attention`` with ``qkv``,
 ``kv_write``, ``core`` and ``out``, ``ffn`` or ``moe``, ``head``,
-``cache_io``), which XLA keeps in each operation's metadata, and its two
+``cache_io``, and in RG-LRU and Mamba-2 blocks ``recurrent`` and ``ssm``),
+which XLA keeps in each operation's metadata, and its two
 engine programs are named (``jit_engine_prefill``, ``jit_engine_decode``).
 
 From those this module gives:
@@ -37,7 +38,7 @@ PROGRAM_PREFIX = "repro."
 NO_SPAN = "no program span"
 UNSCOPED = "unscoped"
 SCOPES = frozenset({"embed", "attention", "qkv", "kv_write", "core", "out",
-                    "ffn", "moe", "head", "cache_io"})
+                    "ffn", "moe", "head", "cache_io", "recurrent", "ssm"})
 WINDOW = T.HOST_PREFIX + "window"
 MODULES_LINE = "XLA Modules"
 CACHE_KEY = "program_trace"     # where ``load`` keeps its reduction

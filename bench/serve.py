@@ -9,6 +9,10 @@ falls due and steps the scheduler; a thin tap between scheduler and engine
 stamps every token handed back on the host clock and counts the work each
 decode step needs.  After the window the program's state is freed and the
 reference re-reads a sample of the finished requests.
+
+Weights, the program's parameters, the reference and the work counts all
+come from the configuration's family module (``cell.family``; see
+``bench.registry.load_family``).
 """
 from __future__ import annotations
 
@@ -20,8 +24,7 @@ import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from bench import gen, work
-from bench.work import Sizes
+from bench import gen
 
 STATE_FAILED = ("FAILED", "REJECTED", "CANCELLED")
 
@@ -47,8 +50,9 @@ class Tap:
     through.  Around ``admit`` and ``decode`` it opens a host span, stamps
     the tokens handed back, and adds up the work the decode steps need."""
 
-    def __init__(self, engine, sizes: Sizes, records: dict):
-        self._engine, self.sizes, self.records = engine, sizes, records
+    def __init__(self, engine, family, sizes, records: dict):
+        self._engine, self.family, self.sizes = engine, family, sizes
+        self.records = records
         self.sched = None
         self.decode_flops = self.decode_bytes = self.prefill_flops = 0
         self._prefilled: set = set()
@@ -67,8 +71,8 @@ class Tap:
         t = time.perf_counter()
         if req.rid not in self._prefilled:
             self._prefilled.add(req.rid)
-            self.prefill_flops += work.prefill_flops(self.sizes,
-                                                     len(req.prompt))
+            self.prefill_flops += self.family.prefill_flops(
+                self.sizes, len(req.prompt))
         rec = self.records[req.rid]
         rec.slot = slot
         self._deliver(rec, 1, t)
@@ -87,8 +91,8 @@ class Tap:
                           t)
         n = max((len(v) for v in out.values()), default=0)
         for k in range(n):
-            f, b = work.decode_step_work(self.sizes,
-                                         [ctx[s] + k for s in out])
+            f, b = self.family.decode_step_work(self.sizes,
+                                                [ctx[s] + k for s in out])
             self.decode_flops += f
             self.decode_bytes += b
         return out
@@ -146,7 +150,8 @@ def _warm(engine, engine_kw: dict, multiple: int) -> None:
 class Served:
     """A set-up engine, ready for windows."""
     engine: object
-    sizes: Sizes
+    family: object             # the configuration's family module
+    sizes: object              # its sizes
     recorder: object
     clock: _Clock
     max_len: int
@@ -157,20 +162,20 @@ def setup(cell, *, seed: int, trace: bool) -> Served:
     """Weights from the seed, the engine, and every program warmed."""
     from repro.obs import Registry, TraceRecorder
     from repro.serve import PagedEngine
-    from bench import program
 
-    s = Sizes.from_config(cell.config)
+    fam = cell.family
+    s = fam.sizes(cell.config)
     ekw = dict(cell.workload["engine"])
     swap = ekw.pop("host_swap_bytes", None)
-    cfg = program.model_config(cell.config)
-    params = program.program_params(seed, s, cfg)
+    cfg = fam.model_config(cell.config)
+    params = fam.program_params(seed, s, cfg)
     clock = _Clock()
     recorder = TraceRecorder(capacity=1 << 20, enabled=trace, clock=clock)
     engine = PagedEngine(cfg, params, metrics=Registry(), trace=recorder,
                          **ekw)
     _warm(engine, ekw, int(cell.traffic["prompt"].get("multiple", 1)))
-    return Served(engine=engine, sizes=s, recorder=recorder, clock=clock,
-                  max_len=ekw["max_len"], swap=swap)
+    return Served(engine=engine, family=fam, sizes=s, recorder=recorder,
+                  clock=clock, max_len=ekw["max_len"], swap=swap)
 
 
 def measure(st: Served, traffic: dict, *, seed: int, seconds: float,
@@ -185,7 +190,7 @@ def measure(st: Served, traffic: dict, *, seed: int, seconds: float,
     due = gen.schedule(traffic, seconds=seconds, seed=seed, vocab=s.vocab)
     compiles = CompileCounter()
     records: dict[int, Record] = {}
-    tap = Tap(engine, s, records)
+    tap = Tap(engine, st.family, s, records)
     sched = Scheduler(tap, host_swap_bytes=st.swap, trace=recorder)
     tap.sched = sched
     before = {k: getattr(engine, k) for k in COUNTERS}
@@ -238,7 +243,7 @@ def measure(st: Served, traffic: dict, *, seed: int, seconds: float,
         r.state, r.state_at_close = req.state.name, at_close[rid]
         r.prompt, r.output = list(req.prompt), list(req.output)
     sched.drain()
-    return dict(kind="serve", sizes=s, window=(t0, t1),
+    return dict(kind="serve", family=st.family, sizes=s, window=(t0, t1),
                 records=list(records.values()), counters=counters,
                 work=work_done,
                 spans=recorder.events()[n_events:] if recorder else None,
@@ -296,11 +301,10 @@ def check(rec: dict, *, seed: int, limits: dict, control: bool = False):
     widest gap by which a served token's logit lies below the reference's
     best at that position.  Returns {name: (value, limit)}, plus the
     control's widest gap when ``control``."""
-    from bench import reference, weights
-    s: Sizes = rec["sizes"]
+    fam, s = rec["family"], rec["sizes"]
     picked = sample(rec, seed, int(limits["sample_tokens"]))
     short = sum(len(r.output) != r.gen for r in picked)
-    w = weights.bench_weights(seed, s)
+    w = fam.bench_weights(seed, s)
     rows, length = rec["max_gen"], rec["max_len"]
     worst = worst_ctl = 0.0
     for r in picked:
@@ -309,8 +313,8 @@ def check(rec: dict, *, seed: int, limits: dict, control: bool = False):
         toks[:len(seq)] = seq
         served = np.zeros(rows, np.int32)
         served[:len(r.output)] = r.output
-        got = reference.served_gaps(w, toks, r.prompt_len - 1, served, s=s,
-                                    rows=rows, control=control)
+        got = fam.served_gaps(w, toks, r.prompt_len - 1, served, s=s,
+                              rows=rows, control=control)
         if control:
             got, ctl = got
             worst_ctl = max(worst_ctl, float(np.max(ctl[:len(r.output)])))

@@ -71,6 +71,21 @@ def test_scope_of_an_op_name():
     assert P.scope_of("") == P.UNSCOPED
 
 
+@pytest.mark.parametrize("scope", ["ssm", "recurrent"])
+def test_recurrent_and_ssm_ops_are_charged_to_their_scope(scope):
+    name = (f"jit(engine_decode)/while/body/closed_call/{scope}/"
+            "dot_general:")
+    assert P.scope_of(name) == scope
+    ops = {DEV: [((DECODE, P.scope_of(name)), 0.0, 2.0),
+                 ((DECODE, P.scope_of("jit(engine_decode)/add:")), 2.0,
+                  3.0)]}
+    by = P.device_by_scope(ops, (0.0, 10.0))
+    assert by == pytest.approx({(DECODE, scope): 2.0,
+                                (DECODE, P.UNSCOPED): 1.0})
+    assert P.module_scope_seconds({"device_by_scope": by}, DECODE,
+                                  scope) == 2.0
+
+
 def _xspace(device_ops, modules, host):
     """A serialized XSpace: one TPU plane with "XLA Modules" and "XLA Ops"
     lines (ops carry ``program_id`` and ``tf_op`` in their metadata), and

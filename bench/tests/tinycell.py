@@ -45,16 +45,24 @@ TINY_WORKLOAD = {
 
 
 def write_cell(root: Path, cell: str, config: str, *, traffic=None,
-               workload=None, metrics=("setup_s", "ttft_p75_s")) -> Path:
+               workload=None, metrics=("setup_s", "ttft_p75_s"),
+               family=None) -> Path:
     """Add one cell to a copy of the checkout's BENCHMARK.json under
     ``root``, as new files only.  ``metrics`` names the end-to-end
-    metrics the cell reports."""
+    metrics the cell reports; ``family``, a pair (name, source), adds the
+    family module ``bench/families/<name>.py`` and names it in the
+    configuration file."""
     root = Path(root)
-    for sub in ("configs", "traffic", "workloads", "metrics"):
+    for sub in ("configs", "traffic", "workloads", "metrics", "families"):
         (root / "bench" / sub).mkdir(parents=True, exist_ok=True)
     bm = json.loads((REPO / "BENCHMARK.json").read_text())
+    conf = dict(TINY_CONFIGS[config])
+    if family is not None:
+        name, source = family
+        (root / "bench" / "families" / f"{name}.py").write_text(source)
+        conf["family"] = name
     (root / "bench" / "configs" / f"{config}.json").write_text(
-        json.dumps(TINY_CONFIGS[config]))
+        json.dumps(conf))
     (root / "bench" / "traffic" / f"{cell}.json").write_text(
         json.dumps(traffic or TINY_TRAFFIC))
     (root / "bench" / "workloads" / f"{cell}.json").write_text(
